@@ -67,6 +67,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
+from repro.launch.cache import enable_compile_cache
 from repro.models.attention import paged_gather
 from repro.models.common import NEG_INF
 from repro.serve.engine import view_bucket
@@ -242,7 +243,7 @@ def bench_prefill_case(*, B, KV, G, hd, max_len, block_size, occupancy,
 
     kv_elem = KV * hd * 2 * itemsize
     cpb = ops.pick_block_chunk(cwidth, block_size, head_dim=hd,
-                               dtype_bytes=itemsize)
+                               kv_heads=KV, dtype_bytes=itemsize)
     span = cpb * block_size
     needed = np.minimum(vlen, -(-row_fill // span) * span)
     out = {
@@ -278,6 +279,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="shrink the sweep for the CI bench-smoke job")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.arch:
         from repro.serve.spec import ServeSpec
         cfg = ServeSpec(arch=args.arch, smoke=args.smoke).build_config()

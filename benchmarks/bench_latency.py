@@ -30,15 +30,17 @@ cancelled/timed-out partials conserve energy — are what the checker and the
 tier-1 suite pin down.
 
 ``--multihost`` instead runs the data-parallel weak-scaling comparison
-(docs/serving.md "Multi-device serving"): one child process per device count
-(1/2/4 simulated via ``--xla_force_host_platform_device_count``), each
-serving the same deterministic open-burst workload through the streaming
-front-end on an ``n_shards = n_devices`` engine, written into the
+(docs/serving.md "Multi-device serving"): in one process, for each device
+count (1/2/4, meshes over ``jax.devices()[:n]``), the same deterministic
+open-burst workload is served through the streaming front-end on an
+``n_shards = n_devices`` engine, written into the
 ``multihost`` section — token identity across device counts, per-shard
 energy-ledger conservation, occupancy balance, and the 4-device decode
 speedup are gated by ``scripts/check_bench_json.py`` (the speedup bound
 conditions on the recorded ``host_cpus``: a 1-core host serializes the
-per-device programs, capping wall-clock scaling near 1x by physics).
+per-device programs, capping wall-clock scaling near 1x by physics).  It
+needs four visible devices; on CPU start it with
+``XLA_FLAGS=--xla_force_host_platform_device_count=4``.
 """
 from __future__ import annotations
 
@@ -51,6 +53,7 @@ from collections import Counter
 import jax
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
 from repro.models import lm
 from repro.nn.param import init_params
 from repro.serve.engine import GenRequest, view_bucket
@@ -189,34 +192,22 @@ def run_poisson(spec, cfg, params, *, rate_rps, n_requests, prompt_lo=6,
     }
 
 
-# -- multihost: 1 vs 2 vs 4 simulated devices --------------------------------
+# -- multihost: 1 vs 2 vs 4 devices ------------------------------------------
 #
-# `XLA_FLAGS=--xla_force_host_platform_device_count=N` must be set before jax
-# initializes, so each device count runs in its own subprocess (spawned with
-# the flag in its environment); the parent never touches jax for these runs.
+# Every device count runs in this one process, on the first n of
+# jax.devices() — a chip belongs to one process, so no child may need it.
 # Weak scaling: the per-shard batch is fixed (`--batch`), so N devices serve
 # an N-times larger decode batch — the throughput axis the data-parallel
-# engine buys.  Every child serves the *same* deterministic workload with the
-# per-row DAC scale + frozen noise, so the sharded runs must be
+# engine buys.  Every device count serves the *same* deterministic workload
+# with the per-row DAC scale + frozen noise, so the sharded runs must be
 # token-identical to the single-device baseline at temperature 0 (gated by
 # scripts/check_bench_json.py, like paged_vs_contiguous).
 
-def run_multihost_child(args):
-    """One device count, inside the XLA_FLAGS-forced subprocess: serve the
-    fixed workload on an n-shard engine, print the metrics JSON on stdout."""
-    n = args.multihost_child
-    if jax.device_count() != n:
-        raise SystemExit(f"multihost child expected {n} devices, got "
-                         f"{jax.device_count()} — XLA_FLAGS not applied?")
+def run_multihost_count(args, spec, cfg, params, n):
+    """Serve the fixed workload on an n-shard engine over jax.devices()[:n];
+    returns that device count's metrics."""
     batch = args.batch * n
-    # per-row DAC scale: co-tenant occupancy cannot perturb tokens, so the
-    # sharded runs are comparable token-for-token with the baseline
-    spec = ServeSpec(arch=args.arch, mode=args.mode, smoke=True,
-                     a_per_row=True, batch_size=batch, max_len=64, seed=7,
-                     frozen_noise=True, paged=True, block_size=8, shards=n)
-    cfg = spec.build_config()
-    params = init_params(lm.specs(cfg), jax.random.PRNGKey(0))
-    eng = spec.build_engine(cfg, params)
+    eng = spec.replace(batch_size=batch, shards=n).build_engine(cfg, params)
     rng = np.random.default_rng(0)
     _warmup(eng, cfg, rng, 6, 20, args.max_new, batch)
 
@@ -242,7 +233,7 @@ def run_multihost_child(args):
         host_cpus = len(os.sched_getaffinity(0))
     except AttributeError:
         host_cpus = os.cpu_count() or 1
-    out = {
+    return {
         "n_devices": n, "n_shards": n, "batch": batch,
         # simulated devices share the host's cores: with host_cpus == 1 the
         # per-device programs serialize and wall-clock weak scaling is
@@ -272,28 +263,26 @@ def run_multihost_child(args):
             and np.isclose(shard_idle.sum(), eng.idle_energy_pj, rtol=1e-9)),
         "token_ids": [list(map(int, r.tokens)) for r in results],
     }
-    print(json.dumps(out))
 
 
 def run_multihost(args):
-    """Parent: spawn one child per device count, compare tokens, compute the
-    4v1 weak-scaling speedup; returns the `multihost` report section."""
-    import subprocess
-    import sys
-
+    """Serve every device count in turn, compare tokens, compute the 4v1
+    weak-scaling speedup; returns the `multihost` report section."""
+    if jax.device_count() < 4:
+        raise SystemExit(
+            f"--multihost needs 4 devices, found {jax.device_count()} — on "
+            f"CPU start with XLA_FLAGS=--xla_force_host_platform_device_"
+            f"count=4")
+    # per-row DAC scale: co-tenant occupancy cannot perturb tokens, so the
+    # sharded runs are comparable token-for-token with the baseline
+    spec = ServeSpec(arch=args.arch, mode=args.mode, smoke=True,
+                     a_per_row=True, batch_size=args.batch, max_len=64,
+                     seed=7, frozen_noise=True, paged=True, block_size=8)
+    cfg = spec.build_config()
+    params = init_params(lm.specs(cfg), jax.random.PRNGKey(0))
     devices = {}
     for n in (1, 2, 4):
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
-        cmd = [sys.executable, os.path.abspath(__file__),
-               "--multihost-child", str(n), "--arch", args.arch,
-               "--mode", args.mode, "--requests", str(args.requests),
-               "--max-new", str(args.max_new), "--batch", str(args.batch)]
-        proc = subprocess.run(cmd, env=env, capture_output=True, text=True)
-        if proc.returncode != 0:
-            raise SystemExit(f"multihost child ({n} devices) failed:\n"
-                             f"{proc.stdout}\n{proc.stderr}")
-        devices[str(n)] = json.loads(proc.stdout.strip().splitlines()[-1])
+        devices[str(n)] = run_multihost_count(args, spec, cfg, params, n)
         print(f"multihost: {n} device(s): "
               f"{devices[str(n)]['decode_tok_per_s']} tok/s", flush=True)
 
@@ -336,15 +325,11 @@ def main():
                     help="shrink for the CI bench-smoke job (fail on "
                          "exceptions and structure, not on numbers)")
     ap.add_argument("--multihost", action="store_true",
-                    help="run the 1/2/4 simulated-device weak-scaling "
-                         "comparison (subprocess per device count) and write "
+                    help="run the 1/2/4-device weak-scaling comparison "
+                         "(one process; needs 4 visible devices) and write "
                          "the 'multihost' section instead of 'poisson_load'")
-    ap.add_argument("--multihost-child", type=int, default=None,
-                    help=argparse.SUPPRESS)   # internal: one device count
     args = ap.parse_args()
-    if args.multihost_child is not None:
-        run_multihost_child(args)
-        return
+    enable_compile_cache()
     if args.multihost:
         # decode-heavy workload: the weak-scaling claim is about decode
         # throughput, so decode steps must dominate the wall (short prompts,

@@ -47,6 +47,7 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
 from repro.models import lm
 from repro.nn.param import init_params
 from repro.serve.engine import ServingEngine, GenRequest, prefill_bucket
@@ -363,6 +364,7 @@ def main():
                     help="shrink every scenario for the CI bench-smoke job "
                          "(fail on exceptions, not on numbers)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         args.requests = min(args.requests, 4)
         args.max_new = min(args.max_new, 4)

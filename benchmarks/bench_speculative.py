@@ -37,6 +37,7 @@ import time
 import jax
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
 from repro.models import lm
 from repro.nn.param import init_params
 from repro.serve.engine import GenRequest
@@ -99,6 +100,7 @@ def main():
     ap.add_argument("--smoke", action="store_true",
                     help="shrink for the CI bench-smoke job")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         # shrink the request count only: shortening max_new instead would
         # clamp k_eff on a larger fraction of rounds (the last k tokens of a

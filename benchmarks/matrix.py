@@ -50,6 +50,7 @@ from collections import Counter
 import numpy as np
 
 from repro.analysis.frontier import frontier_markdown, frontier_report
+from repro.launch.cache import enable_compile_cache
 from repro.serve.engine import GenRequest, prefill_bucket
 from repro.serve.scheduler import RejectedError
 from repro.serve.server import StreamingServer
@@ -476,6 +477,7 @@ def main():
                     help="CNN training steps behind the accuracy proxy "
                          "(default 30 smoke / 120 full)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.matrix:
         with open(args.matrix) as f:

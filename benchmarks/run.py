@@ -212,6 +212,8 @@ def main() -> None:
     ap.add_argument("--only", default="")
     ap.add_argument("--fast", action="store_true")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     names = [n for n in args.only.split(",") if n] or list(BENCHES)
     unknown = [n for n in names if n not in BENCHES]
     if unknown:

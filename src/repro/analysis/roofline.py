@@ -1,8 +1,8 @@
-"""Roofline model for TPU v5e (the target hardware; this box only compiles).
+"""Roofline model over published per-chip peaks, keyed by ``device_kind``.
 
-    compute_s    = HLO_FLOPs_global   / (chips * 197e12)     [bf16 MXU peak]
-    memory_s     = HLO_bytes_global   / (chips * 819e9)      [HBM]
-    collective_s = collective_bytes_global / (chips * 50e9)  [per-link ICI]
+    compute_s    = HLO_FLOPs_global   / (chips * peak FLOP/s)   [bf16 MXU]
+    memory_s     = HLO_bytes_global   / (chips * HBM bytes/s)
+    collective_s = collective_bytes_global / (chips * ICI bytes/s per link)
 
 ``cost_analysis``/HLO parsing yield *per-chip* numbers (spike-verified); globals
 are per-chip x chips, so the chips cancel — the terms are per-chip seconds. The
@@ -14,9 +14,34 @@ from __future__ import annotations
 
 import dataclasses
 
-PEAK_FLOPS = 197e12        # bf16 / chip
-HBM_BW = 819e9             # bytes/s / chip
-ICI_BW = 50e9              # bytes/s / link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published peaks of one chip."""
+    flops: float          # bf16 FLOP/s
+    hbm_bw: float         # HBM bytes/s
+    ici_bw: float         # chip-to-chip bytes/s per link
+    source: str
+
+
+# keyed by jax.Device.device_kind
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(
+        flops=197e12, hbm_bw=819e9,
+        # 1,600 Gbit/s of interconnect over 4 links
+        ici_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI"),
+}
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    """Peaks of ``device_kind``; a chip not in :data:`PEAKS` is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
 def model_flops(cfg, shape, n_params: int, n_active: int) -> float:
@@ -50,11 +75,13 @@ class Roofline:
     coll_bytes_per_chip: float
     chips: int
     model_flops_global: float
+    device_kind: str
 
     def terms(self) -> dict:
-        compute_s = self.flops_per_chip / PEAK_FLOPS
-        memory_s = self.bytes_per_chip / HBM_BW
-        coll_s = self.coll_bytes_per_chip / ICI_BW
+        peaks = peaks_for(self.device_kind)
+        compute_s = self.flops_per_chip / peaks.flops
+        memory_s = self.bytes_per_chip / peaks.hbm_bw
+        coll_s = self.coll_bytes_per_chip / peaks.ici_bw
         terms = {"compute_s": compute_s, "memory_s": memory_s,
                  "collective_s": coll_s}
         dom = max(terms, key=terms.get)
@@ -64,7 +91,7 @@ class Roofline:
                   if hlo_flops_global else 0.0)
         # roofline fraction: useful-compute time / bound time (how close the
         # step is to the compute roofline if overheads vanished)
-        ideal_s = self.model_flops_global / (self.chips * PEAK_FLOPS)
+        ideal_s = self.model_flops_global / (self.chips * peaks.flops)
         return dict(
             terms,
             dominant=dom,

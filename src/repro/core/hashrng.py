@@ -61,8 +61,12 @@ def tile_uniform_bits(seed, row0, col0, shape, plane=0):
 
 def bits_to_state(bits, probs):
     """Map uniform uint32 -> categorical state index given static state probs."""
-    # u in [0, 1)
-    u = bits.astype(jnp.float32) * (1.0 / 4294967296.0)
+    # u in [0, 1).  The TPU kernel compiler has no uint32 -> float32 cast, so
+    # the word converts as two exact 16-bit halves through int32; their f32
+    # sum rounds once, to the value a direct cast gives.
+    hi = (bits >> 16).astype(jnp.int32).astype(jnp.float32)
+    lo = (bits & _U(0xFFFF)).astype(jnp.int32).astype(jnp.float32)
+    u = (hi * 65536.0 + lo) * (1.0 / 4294967296.0)
     state = jnp.zeros(bits.shape, jnp.int32)
     cum = 0.0
     for i, p in enumerate(probs[:-1]):

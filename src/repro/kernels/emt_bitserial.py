@@ -22,12 +22,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import hashrng
 from repro.core.device import DeviceModel
 
 
-def _kernel(x_ref, w_ref, rho_ref, o_ref, *, bk, bits, seed, base_plane, device):
+def _kernel(x_ref, w_ref, sig_ref, o_ref, *, bk, bits, seed, base_plane, device):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
@@ -36,8 +37,7 @@ def _kernel(x_ref, w_ref, rho_ref, o_ref, *, bk, bits, seed, base_plane, device)
     j = pl.program_id(1)
     xq = x_ref[...].astype(jnp.float32)      # integer-valued levels
     w = w_ref[...]
-    rho = rho_ref[0]
-    sig = device.sigma_rel(rho).astype(jnp.float32)
+    sig = sig_ref[0]
 
     sign = jnp.sign(xq)
     mag = jnp.abs(xq)
@@ -65,7 +65,9 @@ def emt_bitserial_pallas(xq, w, rho, *, device: DeviceModel, bits=7, seed=0,
     assert m % bm == 0 and n % bn == 0 and kdim % bk == 0, \
         f"shapes {(m, kdim, n)} must tile by {(bm, bk, bn)}"
     grid = (m // bm, n // bn, kdim // bk)
-    rho_arr = jnp.asarray(rho, jnp.float32).reshape(1)
+    # sigma_rel(rho) is evaluated outside the kernel: the TPU kernel compiler
+    # has no pow, and the reference evaluates it the same way
+    sig = device.sigma_rel(jnp.asarray(rho, jnp.float32)).reshape(1)
     return pl.pallas_call(
         functools.partial(_kernel, bk=bk, bits=bits, seed=seed,
                           base_plane=base_plane, device=device),
@@ -73,9 +75,9 @@ def emt_bitserial_pallas(xq, w, rho, *, device: DeviceModel, bits=7, seed=0,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1,), lambda i, j, k: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
-    )(xq, w, rho_arr)
+    )(xq, w, sig)

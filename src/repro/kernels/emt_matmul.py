@@ -18,12 +18,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import hashrng
 from repro.core.device import DeviceModel
 
 
-def _kernel(x_ref, w_ref, rho_ref, o_ref, *, bk, seed, plane, device, k0_base):
+def _kernel(x_ref, w_ref, sig_ref, o_ref, *, bk, seed, plane, device, k0_base):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
@@ -32,8 +33,7 @@ def _kernel(x_ref, w_ref, rho_ref, o_ref, *, bk, seed, plane, device, k0_base):
     j = pl.program_id(1)
     x = x_ref[...]
     w = w_ref[...]
-    rho = rho_ref[0]
-    sig = device.sigma_rel(rho).astype(jnp.float32)
+    sig = sig_ref[0]
     # global coordinates of this weight tile
     row0 = k0_base + k * bk
     col0 = j * w.shape[1]
@@ -53,7 +53,9 @@ def emt_matmul_pallas(x, w, rho, *, device: DeviceModel, seed=0, plane=0,
     assert m % bm == 0 and n % bn == 0 and kdim % bk == 0, \
         f"shapes {(m, kdim, n)} must tile by {(bm, bk, bn)}"
     grid = (m // bm, n // bn, kdim // bk)
-    rho_arr = jnp.asarray(rho, jnp.float32).reshape(1)
+    # sigma_rel(rho) is evaluated outside the kernel: the TPU kernel compiler
+    # has no pow, and the reference evaluates it the same way
+    sig = device.sigma_rel(jnp.asarray(rho, jnp.float32)).reshape(1)
     return pl.pallas_call(
         functools.partial(_kernel, bk=bk, seed=seed, plane=plane, device=device,
                           k0_base=0),
@@ -61,9 +63,9 @@ def emt_matmul_pallas(x, w, rho, *, device: DeviceModel, seed=0, plane=0,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((1,), lambda i, j, k: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
-    )(x, w, rho_arr)
+    )(x, w, sig)

@@ -96,7 +96,8 @@ PAGED_ATTN_IMPLS = ("auto", "pallas", "interpret", "ref")
 
 
 def pick_block_chunk(width: int, block_size: int, *, head_dim: int = 128,
-                     dtype_bytes: int = 4, vmem_budget: int = 4 * 2 ** 20):
+                     kv_heads: int = 1, dtype_bytes: int = 4,
+                     vmem_budget: int = 4 * 2 ** 20):
     """Blocks streamed per grid step of the paged attention/prefill kernels.
 
     Occupancy-aware: ``width`` is the (clamped) block-table width — the
@@ -108,12 +109,17 @@ def pick_block_chunk(width: int, block_size: int, *, head_dim: int = 128,
     recurrence and keep the MXU fed per score matmul, small enough that the
     double buffer (2 slots x K+V tiles) stays well inside the VMEM budget.
 
+    Every kv head of a block shares one grid step (the kernels DMA whole
+    (block_size, kv_heads, head_dim) blocks), so a position costs
+    ``kv_heads * head_dim`` elements of VMEM.
+
     Returns a power of two so padded table widths stay minimal.
     """
     if width <= 0:
         return 1
     # positions the VMEM budget allows per slot-pair: 2 slots x 2 arrays
-    pos_budget = max(block_size, vmem_budget // (4 * head_dim * dtype_bytes))
+    pos_budget = max(block_size, vmem_budget //
+                     (4 * kv_heads * head_dim * dtype_bytes))
     span_cap = max(block_size, min(512, pos_budget))
     cpb = max(1, span_cap // block_size)
     cpb = 1 << (cpb.bit_length() - 1)                  # floor to pow2
@@ -180,7 +186,8 @@ def paged_attention(q, k_pool, v_pool, table, mask, *, softcap=0.0,
         # NaN — the de-optimized graph is clean, so this is purely an XLA
         # rewrite hazard (tests/test_paged_attention.py enc-dec harness).
         return jax.lax.optimization_barrier(out)
-    cpb = pick_block_chunk(T, bs, head_dim=q.shape[-1])
+    cpb = pick_block_chunk(T, bs, head_dim=q.shape[-1],
+                           kv_heads=q.shape[1])
     table, mask = _pad_view(table, mask, k_pool, cpb)
     return paged_attention_pallas(q, k_pool, v_pool, table, mask,
                                   softcap=softcap, block_chunk=cpb,
@@ -232,7 +239,8 @@ def paged_attention_decode(q, k_pool, v_pool, table, mask, k_new, v_new,
             softcap=softcap)
         # same XLA CPU rewrite hazard as paged_attention (see above)
         return jax.lax.optimization_barrier((out, k_pool, v_pool))
-    cpb = pick_block_chunk(T, bs, head_dim=q.shape[-1])
+    cpb = pick_block_chunk(T, bs, head_dim=q.shape[-1],
+                           kv_heads=q.shape[1])
     table, mask = _pad_view(table, mask, k_pool, cpb)
     return paged_attention_decode_pallas(
         q, k_pool, v_pool, table, mask, k_new, v_new, wblk, woff, wok,
@@ -271,7 +279,7 @@ def paged_prefill(q, k_pool, v_pool, table, qpos, *, softcap=0.0,
                                      softcap=softcap)
         out = jax.lax.optimization_barrier(out)
     else:
-        cpb = pick_block_chunk(T, bs, head_dim=hd)
+        cpb = pick_block_chunk(T, bs, head_dim=hd, kv_heads=KV)
         table, _ = _pad_view(table, None, k_pool, cpb)
         qlast = jnp.max(qpe, axis=1).astype(jnp.int32)
         out = paged_prefill_pallas(qt, k_pool, v_pool, table, qpe, qlast,

@@ -14,8 +14,13 @@ Raw-speed layout (this file's second generation — the first pulled one
   pipelining, because the pool tiles it needs are scattered by the block
   table and per-(block) granularity grid steps leave the MXU idle between
   tiny (block_size, hd) matmuls.
-* grid = (B, KV, C) where each C step covers a *chunk* of ``block_chunk``
-  blocks: one (block_chunk * block_size, hd) score matmul per step.
+* grid = (B, C) where each C step covers a *chunk* of ``block_chunk``
+  blocks for every kv head: one (block_chunk * block_size, hd) score
+  matmul per head per step.  The heads share a step because a pool block
+  (block_size, KV, hd) tiles its (KV, hd) minor pair: one head's slice of
+  it is not tile-aligned, so the DMA moves whole blocks and the kernel
+  reads each head out of VMEM with a strided load (MQA moves the squeezed
+  (block_size, hd) tile directly).
   ``kernels/ops.py::pick_block_chunk`` chooses the chunk from the clamped
   view width (occupancy) so small views run in one step and large views
   amortize the online-softmax recurrence.
@@ -25,8 +30,8 @@ Raw-speed layout (this file's second generation — the first pulled one
 * scratch is (8, 128)-lane aligned: the running (max, sum, acc) statistics
   are padded to 8 sublanes (G is usually < 8) and sliced back, so vector
   loads never straddle tile boundaries.
-* the fused write lands the (hd,) K/V rows for the step's token at
-  ``pool[table[b, wpos // bs], wpos % bs, h]`` *before* chunk 0's read DMA
+* the fused write lands the (KV, hd) K/V rows for the step's token at
+  ``pool[table[b, wpos // bs], wpos % bs]`` *before* chunk 0's read DMA
   is issued — the token always sees its own write, matching the scatter-
   then-attend ordering of the fallback path bit-for-bit.
 
@@ -79,29 +84,49 @@ def _stats_rows(g: int) -> int:
     return max(_SUBLANE, -(-g // _SUBLANE) * _SUBLANE)
 
 
+def _pool_block(pool, blk, kv: int):
+    """DMA source for one pool block.  A single kv head moves as its squeezed
+    (bs, hd) tile; several heads move as the whole (bs, KV, hd) block, which
+    is contiguous in HBM — a one-head slice of the tiled (KV, hd) minor pair
+    is not tile-aligned, and the DMA engine refuses it."""
+    return pool.at[blk, :, 0, :] if kv == 1 else pool.at[blk]
+
+
+def _block_buf(kv: int, cpb: int, bs: int, hd: int, dtype):
+    """2-slot VMEM tile buffer matching :func:`_pool_block`."""
+    shape = (2, cpb, bs, hd) if kv == 1 else (2, cpb, bs, kv, hd)
+    return pltpu.VMEM(shape, dtype)
+
+
+def _head_tile(buf, slot, h: int, kv: int):
+    """(cpb * bs, hd) tile of kv head ``h`` out of a :func:`_block_buf` slot
+    (a sublane-strided load when several heads share the buffer)."""
+    t = buf[slot] if kv == 1 else buf[slot, :, :, h, :]
+    return t.reshape(-1, t.shape[-1])
+
+
 def _decode_kernel(table_ref, wblk_ref, woff_ref, wok_ref,
                    q_ref, knew_ref, vnew_ref, mask_ref, k_hbm, v_hbm,
                    o_ref, kout_hbm, vout_hbm,
                    kbuf, vbuf, sem, wsem, m_ref, l_ref, acc_ref,
-                   *, scale, softcap, cpb, bs, G, has_write):
-    """One (batch row, kv head, block chunk) grid step.
+                   *, scale, softcap, cpb, bs, KV, G, has_write):
+    """One (batch row, block chunk) grid step over every kv head.
 
     ``cpb`` blocks stream per step; ``kout_hbm``/``vout_hbm`` alias the input
     pools, and all reads go through the *output* refs so the fused write (at
     chunk 0) is ordered before every chunk read of the same launch.
     """
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    c = pl.program_id(2)
-    C = pl.num_programs(2)
+    c = pl.program_id(1)
+    C = pl.num_programs(1)
 
-    def start_chunk(ci, slot):
+    def copies(ci, slot):
         for i in range(cpb):
             blk = table_ref[b, ci * cpb + i]
-            pltpu.make_async_copy(kout_hbm.at[blk, :, h, :], kbuf.at[slot, i],
-                                  sem.at[slot, 0, i]).start()
-            pltpu.make_async_copy(vout_hbm.at[blk, :, h, :], vbuf.at[slot, i],
-                                  sem.at[slot, 1, i]).start()
+            yield pltpu.make_async_copy(_pool_block(kout_hbm, blk, KV),
+                                        kbuf.at[slot, i], sem.at[slot, 0, i])
+            yield pltpu.make_async_copy(_pool_block(vout_hbm, blk, KV),
+                                        vbuf.at[slot, i], sem.at[slot, 1, i])
 
     @pl.when(c == 0)
     def _init():
@@ -109,63 +134,65 @@ def _decode_kernel(table_ref, wblk_ref, woff_ref, wok_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
         if has_write:
-            # the new token's (hd,) K/V rows land before any read DMA is
+            # the new token's (KV, hd) K/V rows land before any read DMA is
             # issued — the token always sees its own write, like the
             # scatter-then-attend fallback
             @pl.when(wok_ref[b] != 0)
             def _write():
                 kw = pltpu.make_async_copy(
-                    knew_ref.at[0, 0], kout_hbm.at[wblk_ref[b], woff_ref[b], h],
+                    knew_ref.at[0], kout_hbm.at[wblk_ref[b], woff_ref[b]],
                     wsem.at[0])
                 vw = pltpu.make_async_copy(
-                    vnew_ref.at[0, 0], vout_hbm.at[wblk_ref[b], woff_ref[b], h],
+                    vnew_ref.at[0], vout_hbm.at[wblk_ref[b], woff_ref[b]],
                     wsem.at[1])
                 kw.start()
                 vw.start()
                 kw.wait()
                 vw.wait()
-        start_chunk(0, 0)
+        for cp in copies(0, 0):
+            cp.start()
 
     @pl.when(c + 1 < C)
     def _prefetch_next():                       # double buffer: overlap DMA
-        start_chunk(c + 1, (c + 1) % 2)
+        for cp in copies(c + 1, (c + 1) % 2):
+            cp.start()
 
     slot = c % 2
-    for i in range(cpb):
-        pltpu.make_async_copy(kout_hbm.at[0, :, h, :], kbuf.at[slot, i],
-                              sem.at[slot, 0, i]).wait()
-        pltpu.make_async_copy(vout_hbm.at[0, :, h, :], vbuf.at[slot, i],
-                              sem.at[slot, 1, i]).wait()
+    for cp in copies(c, slot):
+        cp.wait()
 
-    k = kbuf[slot].reshape(cpb * bs, -1)                   # (chunk, hd)
-    v = vbuf[slot].reshape(cpb * bs, -1)
-    q = q_ref[0, 0]                                        # (G, hd)
-    s = jax.lax.dot_general(q, k.astype(q.dtype), (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if softcap:                                            # gemma2 logit cap
-        s = softcap * jnp.tanh(s / softcap)
-    s = s + mask_ref[0][None, :]                           # (G, chunk)
+    mask = mask_ref[0]                                     # (1, chunk)
+    for h in range(KV):
+        k = _head_tile(kbuf, slot, h, KV)                  # (chunk, hd)
+        v = _head_tile(vbuf, slot, h, KV)
+        q = q_ref[0, h]                                    # (G, hd)
+        s = jax.lax.dot_general(q, k.astype(q.dtype), (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if softcap:                                        # gemma2 logit cap
+            s = softcap * jnp.tanh(s / softcap)
+        s = s + mask                                       # (G, chunk)
 
-    m_prev = m_ref[0:G]                                    # (G, 1)
-    l_prev = l_ref[0:G]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    # masked lanes must contribute exact zeros even when the whole chunk is
-    # masked; m_safe keeps every exp argument away from sentinel-minus-
-    # sentinel differences (exact in strict fp, NaN-prone under XLA's
-    # reassociating fusions — see kernels/ref.py, which mirrors this)
-    m_safe = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
-    p = jnp.where(s > NEG_INF / 2, jnp.exp(s - m_safe), 0.0)
-    corr = jnp.exp(m_prev - m_safe)
-    l_ref[0:G] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[0:G] = acc_ref[0:G] * corr + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[0:G] = m_new
+        m_prev = m_ref[h, 0:G]                             # (G, 1)
+        l_prev = l_ref[h, 0:G]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        # masked lanes must contribute exact zeros even when the whole chunk
+        # is masked; m_safe keeps every exp argument away from sentinel-
+        # minus-sentinel differences (exact in strict fp, NaN-prone under
+        # XLA's reassociating fusions — see kernels/ref.py, which mirrors
+        # this)
+        m_safe = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
+        p = jnp.where(s > NEG_INF / 2, jnp.exp(s - m_safe), 0.0)
+        corr = jnp.exp(m_prev - m_safe)
+        l_ref[h, 0:G] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[h, 0:G] = acc_ref[h, 0:G] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_ref[h, 0:G] = m_new
 
     @pl.when(c == C - 1)
     def _done():
-        o_ref[...] = (acc_ref[0:G] /
-                      jnp.maximum(l_ref[0:G], 1e-30))[None, None]
+        for h in range(KV):
+            o_ref[0, h] = acc_ref[h, 0:G] / jnp.maximum(l_ref[h, 0:G], 1e-30)
 
 
 def _call(q, k_pool, v_pool, table, mask, knew, vnew, wblk, woff, wok, *,
@@ -178,36 +205,39 @@ def _call(q, k_pool, v_pool, table, mask, knew, vnew, wblk, woff, wok, *,
     assert mask.shape == (B, T * bs), (mask.shape, (B, T * bs))
     assert k_pool.shape == v_pool.shape and k_pool.shape[2] == KV
     C = T // cpb
+    # the mask rows get a unit axis before their last two, so every block's
+    # last two dims are (8, 128)-aligned or equal to the array's (Mosaic)
+    mask = mask[:, None, :]                                # (B, 1, T*bs)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(B, KV, C),
+        grid=(B, C),
         in_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, c, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, hd), lambda b, h, c, *_: (b, h, 0)),
-            pl.BlockSpec((1, 1, hd), lambda b, h, c, *_: (b, h, 0)),
-            pl.BlockSpec((1, cpb * bs), lambda b, h, c, *_: (b, c)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec((1, KV, G, hd), lambda b, c, *_: (b, 0, 0, 0)),
+            pl.BlockSpec((1, KV, hd), lambda b, c, *_: (b, 0, 0)),
+            pl.BlockSpec((1, KV, hd), lambda b, c, *_: (b, 0, 0)),
+            pl.BlockSpec((1, 1, cpb * bs), lambda b, c, *_: (b, 0, c)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, c, *_: (b, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec((1, KV, G, hd), lambda b, c, *_: (b, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, cpb, bs, hd), k_pool.dtype),    # K tiles (2 slots)
-            pltpu.VMEM((2, cpb, bs, hd), v_pool.dtype),    # V tiles
+            _block_buf(KV, cpb, bs, hd, k_pool.dtype),      # K tiles (2 slots)
+            _block_buf(KV, cpb, bs, hd, v_pool.dtype),      # V tiles
             pltpu.SemaphoreType.DMA((2, 2, cpb)),          # per-slot/tile sems
             pltpu.SemaphoreType.DMA((2,)),                 # write sems (K, V)
-            pltpu.VMEM((_stats_rows(G), 1), jnp.float32),  # running max
-            pltpu.VMEM((_stats_rows(G), 1), jnp.float32),  # running sum
-            pltpu.VMEM((_stats_rows(G), hd), jnp.float32),  # out accumulator
+            pltpu.VMEM((KV, _stats_rows(G), 1), jnp.float32),   # running max
+            pltpu.VMEM((KV, _stats_rows(G), 1), jnp.float32),   # running sum
+            pltpu.VMEM((KV, _stats_rows(G), hd), jnp.float32),  # accumulator
         ],
     )
     kernel = functools.partial(
         _decode_kernel, scale=1.0 / np.sqrt(hd),
-        softcap=float(softcap or 0.0), cpb=cpb, bs=bs, G=G,
+        softcap=float(softcap or 0.0), cpb=cpb, bs=bs, KV=KV, G=G,
         has_write=has_write)
     out, k_pool, v_pool = pl.pallas_call(
         kernel,

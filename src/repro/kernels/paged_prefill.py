@@ -9,10 +9,11 @@ tile straight against table-resolved pool tiles with an online softmax: the
 view never exists.
 
 Shape story: the (B, C, H, hd) query chunk is regrouped to (B, KV, C * G, hd)
-— the kv-head axis becomes a grid dimension and the C chunk lanes x G group
-heads collapse into one query-tile row axis, so each grid step runs a single
-(C * G, chunk_positions) score matmul (C and G are both small; fusing them
-keeps the MXU fed).
+— the C chunk lanes x G group heads collapse into one query-tile row axis,
+so each kv head runs a single (C * G, chunk_positions) score matmul per grid
+step (C and G are both small; fusing them keeps the MXU fed).  The grid is
+(B, kv block chunks); every kv head of a chunk is attended in the same step,
+for the tile-alignment reason given in kernels/paged_attention.py.
 
 Causality is derived *in-kernel* from the per-lane query positions instead of
 a materialized (B, 1, C, L) mask: kv position p is visible to query row r iff
@@ -47,79 +48,82 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.paged_attention import NEG_INF, _stats_rows
+from repro.kernels.paged_attention import (NEG_INF, _block_buf, _head_tile,
+                                           _pool_block, _stats_rows)
 
 
 def _prefill_kernel(table_ref, qlast_ref, q_ref, qpos_ref, k_hbm, v_hbm,
                     o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref,
-                    *, scale, softcap, cpb, bs, R):
-    """One (batch row, kv head, kv block chunk) grid step; R = C * G query
-    rows per tile."""
+                    *, scale, softcap, cpb, bs, KV, R):
+    """One (batch row, kv block chunk) grid step over every kv head;
+    R = C * G query rows per head tile."""
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    c = pl.program_id(2)
-    C = pl.num_programs(2)
+    c = pl.program_id(1)
+    C = pl.num_programs(1)
     span = cpb * bs                                   # kv positions per step
 
     def chunk_needed(ci):
         # chunks strictly past the row's furthest visible position are dead
         return ci * span <= qlast_ref[b]
 
-    def start_chunk(ci, slot):
+    def copies(ci, slot):
         for i in range(cpb):
             blk = table_ref[b, ci * cpb + i]
-            pltpu.make_async_copy(k_hbm.at[blk, :, h, :], kbuf.at[slot, i],
-                                  sem.at[slot, 0, i]).start()
-            pltpu.make_async_copy(v_hbm.at[blk, :, h, :], vbuf.at[slot, i],
-                                  sem.at[slot, 1, i]).start()
+            yield pltpu.make_async_copy(_pool_block(k_hbm, blk, KV),
+                                        kbuf.at[slot, i], sem.at[slot, 0, i])
+            yield pltpu.make_async_copy(_pool_block(v_hbm, blk, KV),
+                                        vbuf.at[slot, i], sem.at[slot, 1, i])
 
     @pl.when(c == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        start_chunk(0, 0)
+        for cp in copies(0, 0):
+            cp.start()
 
     @pl.when((c + 1 < C) & chunk_needed(c + 1))
     def _prefetch_next():                             # double buffer
-        start_chunk(c + 1, (c + 1) % 2)
+        for cp in copies(c + 1, (c + 1) % 2):
+            cp.start()
 
     @pl.when(chunk_needed(c))
     def _attend():
         slot = c % 2
-        for i in range(cpb):
-            pltpu.make_async_copy(k_hbm.at[0, :, h, :], kbuf.at[slot, i],
-                                  sem.at[slot, 0, i]).wait()
-            pltpu.make_async_copy(v_hbm.at[0, :, h, :], vbuf.at[slot, i],
-                                  sem.at[slot, 1, i]).wait()
-        k = kbuf[slot].reshape(span, -1)              # (span, hd)
-        v = vbuf[slot].reshape(span, -1)
-        q = q_ref[0, 0]                               # (R, hd)
-        s = jax.lax.dot_general(q, k.astype(q.dtype), (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if softcap:
-            s = softcap * jnp.tanh(s / softcap)
-        # in-kernel causal mask: kv position vs per-row clamped query position
-        qp = qpos_ref[0][:, None]                     # (R, 1)
+        for cp in copies(c, slot):
+            cp.wait()
+        # in-kernel causal mask: kv position vs per-row clamped query
+        # position, shared by every kv head
+        qp = qpos_ref[0, 0][:, None]                  # (R, 1)
         p = c * span + jax.lax.broadcasted_iota(jnp.int32, (R, span), 1)
-        s = s + jnp.where(p <= qp, 0.0, NEG_INF)
+        mask = jnp.where(p <= qp, 0.0, NEG_INF)
+        for h in range(KV):
+            k = _head_tile(kbuf, slot, h, KV)         # (span, hd)
+            v = _head_tile(vbuf, slot, h, KV)
+            q = q_ref[0, h]                           # (R, hd)
+            s = jax.lax.dot_general(q, k.astype(q.dtype),
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) * scale
+            if softcap:
+                s = softcap * jnp.tanh(s / softcap)
+            s = s + mask
 
-        m_prev = m_ref[0:R]
-        l_prev = l_ref[0:R]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        m_safe = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
-        pr = jnp.where(s > NEG_INF / 2, jnp.exp(s - m_safe), 0.0)
-        corr = jnp.exp(m_prev - m_safe)
-        l_ref[0:R] = l_prev * corr + jnp.sum(pr, axis=-1, keepdims=True)
-        acc_ref[0:R] = acc_ref[0:R] * corr + jax.lax.dot_general(
-            pr.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[0:R] = m_new
+            m_prev = m_ref[h, 0:R]
+            l_prev = l_ref[h, 0:R]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            m_safe = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
+            pr = jnp.where(s > NEG_INF / 2, jnp.exp(s - m_safe), 0.0)
+            corr = jnp.exp(m_prev - m_safe)
+            l_ref[h, 0:R] = l_prev * corr + jnp.sum(pr, axis=-1, keepdims=True)
+            acc_ref[h, 0:R] = acc_ref[h, 0:R] * corr + jax.lax.dot_general(
+                pr.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h, 0:R] = m_new
 
     @pl.when(c == C - 1)
     def _done():
-        o_ref[...] = (acc_ref[0:R] /
-                      jnp.maximum(l_ref[0:R], 1e-30))[None, None]
+        for h in range(KV):
+            o_ref[0, h] = acc_ref[h, 0:R] / jnp.maximum(l_ref[h, 0:R], 1e-30)
 
 
 def paged_prefill_pallas(q, k_pool, v_pool, table, qpos, qlast, *,
@@ -145,29 +149,32 @@ def paged_prefill_pallas(q, k_pool, v_pool, table, qpos, qlast, *,
     assert qpos.shape == (B, R), (qpos.shape, (B, R))
     assert k_pool.shape == v_pool.shape and k_pool.shape[2] == KV
     C = T // cpb
+    # unit axis before the last two: the qpos block's last two dims equal
+    # the array's (Mosaic's (8, 128) rule)
+    qpos = qpos[:, None, :]                           # (B, 1, R)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KV, C),
+        grid=(B, C),
         in_specs=[
-            pl.BlockSpec((1, 1, R, hd), lambda b, h, c, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, R), lambda b, h, c, *_: (b, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec((1, KV, R, hd), lambda b, c, *_: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, R), lambda b, c, *_: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, R, hd), lambda b, h, c, *_: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, KV, R, hd), lambda b, c, *_: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, cpb, bs, hd), k_pool.dtype),
-            pltpu.VMEM((2, cpb, bs, hd), v_pool.dtype),
+            _block_buf(KV, cpb, bs, hd, k_pool.dtype),
+            _block_buf(KV, cpb, bs, hd, v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2, cpb)),
-            pltpu.VMEM((_stats_rows(R), 1), jnp.float32),
-            pltpu.VMEM((_stats_rows(R), 1), jnp.float32),
-            pltpu.VMEM((_stats_rows(R), hd), jnp.float32),
+            pltpu.VMEM((KV, _stats_rows(R), 1), jnp.float32),
+            pltpu.VMEM((KV, _stats_rows(R), 1), jnp.float32),
+            pltpu.VMEM((KV, _stats_rows(R), hd), jnp.float32),
         ],
     )
     kernel = functools.partial(
         _prefill_kernel, scale=1.0 / np.sqrt(hd),
-        softcap=float(softcap or 0.0), cpb=cpb, bs=bs, R=R)
+        softcap=float(softcap or 0.0), cpb=cpb, bs=bs, KV=KV, R=R)
     # qpos rides as a VMEM tile (mask arithmetic), qlast as scalar prefetch
     # (control flow)
     return pl.pallas_call(

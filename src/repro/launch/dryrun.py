@@ -49,6 +49,8 @@ TRAIN_RULES = os.environ.get("REPRO_TRAIN_RULES", "train_fsdp_tp")
 SERVE_RULES = os.environ.get("REPRO_SERVE_RULES", "serve_2d")
 EMT_RNG = os.environ.get("REPRO_EMT_RNG", "hash")
 EMT_MODE = os.environ.get("REPRO_EMT_MODE", "analog")
+# the chip the pod meshes stand for (roofline peaks; analysis/roofline.py)
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 def _measure(lowered, label: str) -> dict:
@@ -139,6 +141,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool) -> dict:
         coll_bytes_per_chip=res["collective_bytes_per_chip"],
         chips=chips,
         model_flops_global=mf,
+        device_kind=TARGET_DEVICE_KIND,
     ).terms()
 
     return {

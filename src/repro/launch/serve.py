@@ -4,6 +4,9 @@ inference.
     python -m repro.launch.serve --arch gemma3-1b --smoke --mode analog \
         --requests 8 --stagger 2 --temperature 0.8 --top-k 40
 
+    # published widths on a TPU chip, paged KV through the Pallas kernels
+    python -m repro.launch.serve --no-smoke --paged --paged-attn-impl pallas
+
 Flags (new continuous-batching engine):
     --device NAME      run every layer on one registered technology corner
                        (core/device.py registry: pcm, rram, mlc2, mlc4,
@@ -78,6 +81,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCHS, PLACEMENTS
+from repro.launch.cache import enable_compile_cache
 from repro.models import lm
 from repro.models.attention import paged_attn_plan
 from repro.nn.param import init_params
@@ -178,7 +182,10 @@ def serve_streaming(eng, reqs, *, rate, deadline_s, max_pending, seed=0):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ARCHS), default="gemma3-1b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced-width smoke config (default); --no-smoke "
+                         "serves the published widths")
     ap.add_argument("--mode", default="analog",
                     choices=["ideal", "analog", "bitserial"])
     ap.add_argument("--device", default=None,
@@ -245,6 +252,7 @@ def main():
     ap.add_argument("--max-pending", type=int, default=16,
                     help="admission-queue bound for --rate mode")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.shards > 1 and jax.device_count() < args.shards:
         ap.error(
             f"--shards {args.shards} needs {args.shards} visible devices "

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from repro.configs import ARCHS, get_config
 from repro.data.synthetic import SyntheticLM
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.parallel.sharding import RULES
 from repro.train.optimizer import OptimizerConfig
@@ -47,6 +48,7 @@ def main():
     ap.add_argument("--opt", default="adamw",
                     choices=["adamw", "sgd", "adafactor"])
     args = ap.parse_args()
+    enable_compile_cache()
     if args.placement and args.device:
         ap.error("--placement and --device are mutually exclusive "
                  "(a placement names its corners per layer)")

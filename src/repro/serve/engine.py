@@ -93,7 +93,6 @@ from typing import Callable, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.models import lm
@@ -135,6 +134,18 @@ def make_decode_step(cfg: ModelConfig, mesh: Optional[Mesh], rules=None):
     return decode_step
 
 
+def _next_tokens(logits, temps, top_k, top_p, sample_seeds, sample_pos,
+                 sampled: bool):
+    """The step's sampled tokens.  ``sampled`` is jit-static and False when
+    the caller knows every row is greedy: the program then holds no
+    full-vocab sort, which at a 262k vocab costs tens of seconds of TPU
+    compile per step program (greedy rows get the same argmax either way)."""
+    if not sampled:
+        return sampling.greedy_tokens(logits)
+    return sampling.sample_tokens(logits, temps, top_k, top_p, sample_seeds,
+                                  sample_pos)
+
+
 def make_serve_decode_step(cfg: ModelConfig, mesh: Optional[Mesh], rules=None):
     """Continuous-batching decode: per-slot positions/active mask + fused
     per-slot seeded sampling. Returns (next_tokens, new_cache, energy_pj)."""
@@ -142,13 +153,13 @@ def make_serve_decode_step(cfg: ModelConfig, mesh: Optional[Mesh], rules=None):
 
     def serve_decode_step(params, cache, tokens, index, active, seed,
                           sample_seeds, sample_pos, temps, top_k, top_p,
-                          enc_lens):
+                          enc_lens, sampled=True):
         ctx = Ctx(seed=seed, shard=shard)
         logits, cache, aux = lm.decode_step(params, cache, tokens, index, cfg,
                                             ctx, active=active,
                                             enc_lens=enc_lens)
-        next_tok = sampling.sample_tokens(logits, temps, top_k, top_p,
-                                          sample_seeds, sample_pos)
+        next_tok = _next_tokens(logits, temps, top_k, top_p, sample_seeds,
+                                sample_pos, sampled)
         return next_tok, cache, {"energy_pj": aux["energy_pj"],
                                  "corners": aux["corners"],
                                  "kv_reads": aux["kv_reads"]}
@@ -168,7 +179,7 @@ def make_chunk_step(cfg: ModelConfig, mesh: Optional[Mesh], rules,
 
     def chunk_step(params, cache, tokens, start, ntok, active, seed,
                    sample_seeds, sample_pos, temps, top_k, top_p,
-                   table_g=None, table_l=None, view_len=0):
+                   table_g=None, table_l=None, view_len=0, sampled=True):
         ctx = Ctx(seed=seed, shard=shard)
         pt = pl = None
         if page_lens is not None:
@@ -177,8 +188,8 @@ def make_chunk_step(cfg: ModelConfig, mesh: Optional[Mesh], rules,
         logits, cache, aux = lm.chunk_step(params, cache, tokens, start, ntok,
                                            cfg, ctx, active=active,
                                            page_tables=pt, page_lens=pl)
-        next_tok = sampling.sample_tokens(logits, temps, top_k, top_p,
-                                          sample_seeds, sample_pos)
+        next_tok = _next_tokens(logits, temps, top_k, top_p, sample_seeds,
+                                sample_pos, sampled)
         return next_tok, cache, {"energy_pj": aux["energy_pj"],
                                  "corners": aux["corners"],
                                  "kv_reads": aux["kv_reads"]}
@@ -261,14 +272,14 @@ def make_paged_decode_step(cfg: ModelConfig, mesh: Optional[Mesh], rules,
 
     def paged_decode_step(params, cache, tokens, index, active, seed,
                           sample_seeds, sample_pos, temps, top_k, top_p,
-                          enc_lens, table_g, table_l, view_len):
+                          enc_lens, table_g, table_l, view_len, sampled=True):
         ctx = Ctx(seed=seed, shard=shard)
         logits, cache, aux = lm.decode_step(
             params, cache, tokens, index, cfg, ctx, active=active,
             page_tables={"global": table_g, "local": table_l},
             page_lens=lm.clamped_lens(page_lens, view_len), enc_lens=enc_lens)
-        next_tok = sampling.sample_tokens(logits, temps, top_k, top_p,
-                                          sample_seeds, sample_pos)
+        next_tok = _next_tokens(logits, temps, top_k, top_p, sample_seeds,
+                                sample_pos, sampled)
         return next_tok, cache, {"energy_pj": aux["energy_pj"],
                                  "corners": aux["corners"],
                                  "kv_reads": aux["kv_reads"]}
@@ -382,11 +393,12 @@ def make_sharded_chunk_step(cfg: ModelConfig, mesh: Mesh,
 
     def chunk_step(params, cache, tokens, start, ntok, active, seed,
                    sample_seeds, sample_pos, temps, top_k, top_p,
-                   table_g=None, table_l=None, view_len=0):
+                   table_g=None, table_l=None, view_len=0, sampled=True):
         def local(params, cache, tokens, start, ntok, active, seed,
                   sample_seeds, sample_pos, temps, top_k, top_p, *tables):
             kw = {"table_g": tables[0], "table_l": tables[1],
                   "view_len": view_len} if paged else {}
+            kw["sampled"] = sampled
             next_tok, cache, aux = base(
                 params, cache, tokens, start, ntok, active, seed,
                 sample_seeds, sample_pos, temps, top_k, top_p, **kw)
@@ -396,8 +408,8 @@ def make_sharded_chunk_step(cfg: ModelConfig, mesh: Mesh,
                 sample_seeds, sample_pos, temps, top_k, top_p)
         if paged:
             args += (table_g, table_l)
-        return shard_map(local, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)(*args)
+        return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)(*args)
 
     return chunk_step
 
@@ -416,7 +428,7 @@ def make_sharded_decode_step(cfg: ModelConfig, mesh: Mesh,
 
     def decode_step(params, cache, tokens, index, active, seed,
                     sample_seeds, sample_pos, temps, top_k, top_p, enc_lens,
-                    table_g=None, table_l=None, view_len=0):
+                    table_g=None, table_l=None, view_len=0, sampled=True):
         def local(params, cache, tokens, index, active, seed,
                   sample_seeds, sample_pos, temps, top_k, top_p, enc_lens,
                   *tables):
@@ -424,19 +436,20 @@ def make_sharded_decode_step(cfg: ModelConfig, mesh: Mesh,
                 next_tok, cache, aux = base(
                     params, cache, tokens, index, active, seed,
                     sample_seeds, sample_pos, temps, top_k, top_p, enc_lens,
-                    tables[0], tables[1], view_len)
+                    tables[0], tables[1], view_len, sampled=sampled)
             else:
                 next_tok, cache, aux = base(
                     params, cache, tokens, index, active, seed,
-                    sample_seeds, sample_pos, temps, top_k, top_p, enc_lens)
+                    sample_seeds, sample_pos, temps, top_k, top_p, enc_lens,
+                    sampled=sampled)
             return next_tok, cache, _shard_stack_aux(aux)
 
         args = (params, cache, tokens, index, active, seed, sample_seeds,
                 sample_pos, temps, top_k, top_p, enc_lens)
         if paged:
             args += (table_g, table_l)
-        return shard_map(local, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)(*args)
+        return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)(*args)
 
     return decode_step
 
@@ -451,8 +464,8 @@ def make_sharded_paged_zero(cfg: ModelConfig, mesh: Mesh, page_lens: dict):
     def zero(big, ids_g, ids_l, slot):
         def local(big, ids_g, ids_l, slot):
             return base(big, ids_g[0], ids_l[0], slot[0])
-        return shard_map(local, mesh=mesh, in_specs=(data,) * 4,
-                         out_specs=data, check_rep=False)(
+        return jax.shard_map(local, mesh=mesh, in_specs=(data,) * 4,
+                             out_specs=data, check_vma=False)(
                              big, ids_g, ids_l, slot)
 
     return jax.jit(zero, donate_argnums=(0,))
@@ -468,8 +481,8 @@ def make_sharded_slot_zero(mesh: Mesh):
         def local(big, slot):
             return jax.tree.map(
                 lambda b: b.at[slot[0]].set(0.0, mode="drop"), big)
-        return shard_map(local, mesh=mesh, in_specs=(data, data),
-                         out_specs=data, check_rep=False)(big, slot)
+        return jax.shard_map(local, mesh=mesh, in_specs=(data, data),
+                             out_specs=data, check_vma=False)(big, slot)
 
     return jax.jit(zero, donate_argnums=(0,))
 
@@ -484,8 +497,8 @@ def make_sharded_pool_copy(cfg: ModelConfig, mesh: Mesh):
     def copy(big, src, dst):
         def local(big, src, dst):
             return base(big, src[0], dst[0])
-        return shard_map(local, mesh=mesh, in_specs=(data,) * 3,
-                         out_specs=data, check_rep=False)(big, src, dst)
+        return jax.shard_map(local, mesh=mesh, in_specs=(data,) * 3,
+                             out_specs=data, check_vma=False)(big, src, dst)
 
     return jax.jit(copy, donate_argnums=(0,))
 
@@ -666,10 +679,12 @@ class ServingEngine:
                     NamedSharding(mesh, PartitionSpec("data")))
                 self._decode = jax.jit(
                     make_sharded_decode_step(cfg, mesh, lens),
-                    donate_argnums=(1,), static_argnames=("view_len",))
+                    donate_argnums=(1,),
+                    static_argnames=("view_len", "sampled"))
                 self._chunk = jax.jit(
                     make_sharded_chunk_step(cfg, mesh, lens),
-                    donate_argnums=(1,), static_argnames=("view_len",))
+                    donate_argnums=(1,),
+                    static_argnames=("view_len", "sampled"))
                 self._zero_retired = make_sharded_paged_zero(cfg, mesh, lens)
                 self._insert = None      # chunked admission never scatters
             else:
@@ -679,7 +694,8 @@ class ServingEngine:
                 # view_len is static: one compile per power-of-two bucket
                 self._decode = jax.jit(
                     make_paged_decode_step(cfg, mesh, rules, lens),
-                    donate_argnums=(1,), static_argnames=("view_len",))
+                    donate_argnums=(1,),
+                    static_argnames=("view_len", "sampled"))
                 self._insert = jax.jit(
                     make_paged_insert(cfg, block_size, lens),
                     donate_argnums=(0,))
@@ -689,7 +705,7 @@ class ServingEngine:
                     self._chunk = jax.jit(
                         make_chunk_step(cfg, mesh, rules, lens),
                         donate_argnums=(1,),
-                        static_argnames=("view_len",))
+                        static_argnames=("view_len", "sampled"))
             self.scheduler = Scheduler(batch_size, kv=self.kv,
                                        max_pending=max_pending,
                                        n_shards=self.n_shards)
@@ -701,21 +717,24 @@ class ServingEngine:
                     self.cache,
                     NamedSharding(mesh, PartitionSpec("data")))
                 self._decode = jax.jit(make_sharded_decode_step(cfg, mesh),
-                                       donate_argnums=(1,))
+                                       donate_argnums=(1,),
+                                       static_argnames=("sampled",))
                 self._chunk = jax.jit(make_sharded_chunk_step(cfg, mesh),
-                                      donate_argnums=(1,))
+                                      donate_argnums=(1,),
+                                      static_argnames=("sampled",))
                 self._zero_retired = make_sharded_slot_zero(mesh)
                 self._insert = None
             else:
                 self._decode = jax.jit(
                     make_serve_decode_step(cfg, mesh, rules),
-                    donate_argnums=(1,))
+                    donate_argnums=(1,), static_argnames=("sampled",))
                 self._insert = jax.jit(self._insert_slot, donate_argnums=(0,))
                 self._zero_retired = jax.jit(self._zero_slot,
                                              donate_argnums=(0,))
                 if self.chunked:
                     self._chunk = jax.jit(make_chunk_step(cfg, mesh, rules),
-                                          donate_argnums=(1,))
+                                          donate_argnums=(1,),
+                                          static_argnames=("sampled",))
             self.scheduler = Scheduler(batch_size, max_pending=max_pending,
                                        n_shards=self.n_shards)
         if sharded:
@@ -981,6 +1000,7 @@ class ServingEngine:
                 sh = self._shard_of(i)
                 needs[sh] = max(needs[sh], 1 + s.pos)
             extra, kwargs = self._paged_tables(needs)
+        kwargs["sampled"] = bool((temps > 0).any())
         step_seed = self.seed + self._steps + 1 if self.fresh_noise else self.seed
         next_tok, self.cache, eaux = self._decode(
             self.params, self.cache, jnp.asarray(tokens), jnp.asarray(index),
@@ -1047,6 +1067,7 @@ class ServingEngine:
                 sh = self._shard_of(i)
                 needs[sh] = max(needs[sh], int(start[i] + ntok[i]))
             extra, kwargs = self._paged_tables(needs)
+        kwargs["sampled"] = bool((temps > 0).any())
         step_seed = self.seed + self._steps + 1 if self.fresh_noise else self.seed
         next_tok, self.cache, eaux = self._chunk(
             self.params, self.cache, jnp.asarray(tokens), jnp.asarray(start),

@@ -43,6 +43,11 @@ def gumbel_noise(seeds, positions, vocab: int):
     return -jnp.log(-jnp.log(u))
 
 
+def greedy_tokens(logits):
+    """(B, V) logits -> (B,) int32 argmax: the temperature-0 token."""
+    return jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
+
+
 def sample_tokens(logits, temperature, top_k, top_p, seeds, positions):
     """Sample one token per row. All args (B,)-shaped except logits (B, V).
 
@@ -54,7 +59,7 @@ def sample_tokens(logits, temperature, top_k, top_p, seeds, positions):
     """
     B, V = logits.shape
     lf = logits.astype(jnp.float32)
-    greedy = jnp.argmax(lf, axis=-1).astype(jnp.int32)
+    greedy = greedy_tokens(lf)
     t = jnp.asarray(temperature, jnp.float32)
 
     def _sampled():

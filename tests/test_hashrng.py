@@ -44,3 +44,25 @@ def test_state_probabilities_two_and_four():
         # empirical moments ~ (0, 1)
         assert abs(offs.mean()) < 0.01
         assert abs(offs.std() - 1.0) < 0.01
+
+
+def test_bits_to_state_matches_direct_cast():
+    """The kernel-safe conversion (two exact 16-bit halves) gives the same
+    u as a direct uint32 -> float32 cast, so states stay bit-identical."""
+    rng = np.random.default_rng(0)
+    edge = np.array([0, 1, 2**16 - 1, 2**16, 2**24 - 1, 2**24, 2**24 + 1,
+                     2**24 + 3, 2**31 - 1, 2**31, 2**32 - 129, 2**32 - 128,
+                     2**32 - 1], np.uint64)
+    words = np.concatenate([edge, rng.integers(0, 2**32, 1 << 16,
+                                               dtype=np.uint64)])
+    words = words.astype(np.uint32)
+    for dev in (DeviceModel(), four_state_device()):
+        got = np.asarray(hashrng.bits_to_state(jnp.asarray(words),
+                                               dev.state_probs))
+        u = words.astype(np.float32) * np.float32(1.0 / 2**32)
+        want = np.zeros(words.shape, np.int32)
+        cum = 0.0
+        for i, p in enumerate(dev.state_probs[:-1]):
+            cum += p
+            want = np.where(u >= np.float32(cum), i + 1, want)
+        np.testing.assert_array_equal(got, want)

@@ -48,3 +48,21 @@ def test_async_pairs_counted_once():
 def test_while_detected():
     hlo = "%w = (s32[], f32[4]) while(%t), condition=%c, body=%b"
     assert analyze_collectives(hlo)["num_while"] == 1
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    import pytest
+
+    from repro.analysis.roofline import Roofline, peaks_for
+
+    v5e = peaks_for("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    assert "TPU v5e" in v5e.source
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks_for("cpu")
+    roof = Roofline(flops_per_chip=197e12, bytes_per_chip=0.0,
+                    coll_bytes_per_chip=0.0, chips=1,
+                    model_flops_global=197e12, device_kind="TPU v5 lite")
+    assert roof.terms()["compute_s"] == 1.0
+    with pytest.raises(ValueError):
+        Roofline(1.0, 1.0, 0.0, 1, 1.0, device_kind="TPU v9").terms()

@@ -91,13 +91,16 @@ def test_generate_backcompat_and_eos(setup):
         np.testing.assert_array_equal(a, b)
     assert e1 > 0 and abs(e1 - e2) / e1 < 1e-6
 
-    # eos stops early: use the first generated token as the eos id
+    # eos stops early: use the first generated token as the eos id.  The
+    # same pair is resubmitted because the analog DAC scale is per tensor
+    # over the batch, so a row's tokens depend on its co-tenants: only the
+    # co-tenant set generate() ran with reproduces that first token.
     eos = int(outs1[0][0])
     eng2 = ServingEngine(cfg, params, batch_size=2, max_len=16, seed=3)
-    res = None
-    eng2.submit(GenRequest(prompt=reqs[0].prompt, max_new=4, eos_id=eos))
-    for r in eng2.drain():
-        res = r
+    rid = eng2.submit(GenRequest(prompt=reqs[0].prompt, max_new=4,
+                                 eos_id=eos))
+    eng2.submit(reqs[1])
+    res = {r.rid: r for r in eng2.drain()}[rid]
     assert res.done_reason == "eos" and len(res.tokens) == 1
 
 
