@@ -65,13 +65,15 @@ def _bitserial_fwd(xq, w, rho, device: DeviceModel, bits: int, seed, base_plane)
     sig = device.sigma_rel(rho)
     acc = None
     for p in range(bits):
-        offs = hashrng.tile_state_offsets(
-            seed, 0, 0, (k, n), device.state_offsets, device.state_probs,
-            plane=base_plane + p)
-        wn = w * (1.0 + offs.astype(w.dtype) * sig.astype(w.dtype))
-        planes = (sign * bit_plane(mag, p)).astype(w.dtype)
-        term = (2.0 ** p) * jnp.matmul(planes, wn)
-        acc = term if acc is None else acc + term
+        with jax.named_scope("emt.noise"):
+            offs = hashrng.tile_state_offsets(
+                seed, 0, 0, (k, n), device.state_offsets, device.state_probs,
+                plane=base_plane + p)
+            wn = w * (1.0 + offs.astype(w.dtype) * sig.astype(w.dtype))
+        with jax.named_scope("emt.crossbar"):
+            planes = (sign * bit_plane(mag, p)).astype(w.dtype)
+            term = (2.0 ** p) * jnp.matmul(planes, wn)
+            acc = term if acc is None else acc + term
     return acc
 
 

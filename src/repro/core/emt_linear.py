@@ -15,6 +15,13 @@ Modes
 Every call returns ``(y, aux)`` where ``aux`` carries the differentiable
 energy-regularization term (Eq. 13), the analytic energy estimate in pJ, cell and
 read counts — aggregated up the model with :func:`add_aux`.
+
+Every op of a call carries the layer's ``tag`` and its phase in its name stack
+(``jax.named_scope``, HLO ``op_name`` metadata, so profiler traces group ops per
+projection and phase): ``emt.weights`` (quantize or int8 dequantize),
+``emt.inputs`` (DAC levels and their read counts), ``emt.noise`` (read
+fluctuation), ``emt.crossbar`` (the matmul) and ``emt.energy`` (the accounting
+from the weights).  Scopes change neither the program's ops nor their fusion.
 """
 from __future__ import annotations
 
@@ -172,10 +179,15 @@ def emt_dense(params: dict, x, cfg: EMTConfig, *, tag: str,
     """Apply the layer. Returns (y, aux).
 
     tag:  unique layer name — seeds the per-layer noise plane (hash backend) or the
-          fold_in constant (threefry backend).
+          fold_in constant (threefry backend), and scopes the layer's ops.
     seed: uint32 scalar (traced is fine) — typically derived from the training step,
           so technique A sees fresh fluctuation data every batch.
     """
+    with jax.named_scope(tag):
+        return _emt_dense(params, x, cfg, tag=tag, seed=seed, key=key)
+
+
+def _emt_dense(params, x, cfg, *, tag, seed, key):
     int8_weights = "w_int8" in params
     w = params["w_int8"] if int8_weights else params["w"]
     aux = new_aux()
@@ -183,7 +195,8 @@ def emt_dense(params: dict, x, cfg: EMTConfig, *, tag: str,
     plane = _tag_plane(tag)
 
     if not cfg.active:
-        y = x @ w
+        with jax.named_scope("emt.crossbar"):
+            y = x @ w
         if "b" in params:
             y = y + params["b"]
         return y, aux
@@ -193,46 +206,56 @@ def emt_dense(params: dict, x, cfg: EMTConfig, *, tag: str,
         rho = jax.lax.stop_gradient(rho)
 
     # --- weights onto the crossbar: quantize (stored conductances) ----------
-    if int8_weights:
-        # already stored as conductance levels; dequantize on-chip (fuses into
-        # the matmul input on TPU — weight HBM traffic stays int8-sized)
-        wq = (w.astype(x.dtype) * params["w_scale"].astype(x.dtype))
-    else:
-        wq, _ = quantize_weights(w, cfg.quant)
+    with jax.named_scope("emt.weights"):
+        if int8_weights:
+            # already stored as conductance levels; dequantize on-chip (fuses into
+            # the matmul input on TPU — weight HBM traffic stays int8-sized)
+            wq = (w.astype(x.dtype) * params["w_scale"].astype(x.dtype))
+        else:
+            wq, _ = quantize_weights(w, cfg.quant)
     # --- activations onto the input lines: quantized DAC levels -------------
     # per_row: each batch row (token) gets its own DAC scale, so quantization
     # never couples co-tenant rows (occupancy-independent serving); per-tensor
     # is the paper's default and marginally cheaper.
-    a_axis = -1 if cfg.quant.a_per_row else None
-    levels, a_scale = quant_levels(x, cfg.quant.a_bits, axis=a_axis)
+    with jax.named_scope("emt.inputs"):
+        a_axis = -1 if cfg.quant.a_per_row else None
+        levels, a_scale = quant_levels(x, cfg.quant.a_bits, axis=a_axis)
 
     n_tokens = _tokens(x)
     if cfg.mode == "analog":
-        if key is not None:
-            key = jax.random.fold_in(key, plane)
-        wn = fluctuate(wq, rho, cfg.device, cfg.noise, key=key,
-                       seed=seed, plane=plane)
-        y = (levels * a_scale) @ wn
+        with jax.named_scope("emt.noise"):
+            if key is not None:
+                key = jax.random.fold_in(key, plane)
+            wn = fluctuate(wq, rho, cfg.device, cfg.noise, key=key,
+                           seed=seed, plane=plane)
+        with jax.named_scope("emt.crossbar"):
+            y = (levels * a_scale) @ wn
         # mean analog input level in LEVEL units (x = sum_p delta_p 2^p, Eq. 14) so
         # it is directly comparable with the bit-serial popcount of Eq. 19.
-        x_level = jax.lax.stop_gradient(jnp.mean(jnp.abs(levels)))
+        with jax.named_scope("emt.inputs"):
+            x_level = jax.lax.stop_gradient(jnp.mean(jnp.abs(levels)))
         reads_per_cell = float(n_tokens)
     elif cfg.mode == "bitserial":
         if cfg.use_pallas:
             from repro.kernels import ops as kops  # lazy: kernels depend on core
-            y_raw = kops.emt_bitserial_matmul(
-                levels.reshape(-1, d_in), wq, rho, device=cfg.device,
-                bits=cfg.quant.a_bits - 1, seed=seed, base_plane=plane,
-                interpret=cfg.pallas_interpret)
-            y_raw = y_raw.reshape(*x.shape[:-1], d_out)
+            # the kernel draws each bit-plane's noise inside its matmul
+            with jax.named_scope("emt.crossbar"):
+                y_raw = kops.emt_bitserial_matmul(
+                    levels.reshape(-1, d_in), wq, rho, device=cfg.device,
+                    bits=cfg.quant.a_bits - 1, seed=seed, base_plane=plane,
+                    interpret=cfg.pallas_interpret)
+                y_raw = y_raw.reshape(*x.shape[:-1], d_out)
         else:
+            # scopes each bit-plane's emt.noise and emt.crossbar itself
             y_raw = decompose.bitserial_matmul_ref(
                 levels, wq, rho, cfg.device, cfg.quant.a_bits - 1,
                 seed=seed, base_plane=plane)
-        y = y_raw * a_scale
+        with jax.named_scope("emt.crossbar"):
+            y = y_raw * a_scale
         # energy counts actual bit reads (Eq. 19): popcount of levels
-        pops = decompose.popcount_levels(jnp.abs(levels), cfg.quant.a_bits - 1)
-        x_level = jax.lax.stop_gradient(jnp.mean(pops))
+        with jax.named_scope("emt.inputs"):
+            pops = decompose.popcount_levels(jnp.abs(levels), cfg.quant.a_bits - 1)
+            x_level = jax.lax.stop_gradient(jnp.mean(pops))
         reads_per_cell = float(n_tokens)  # per bit handled via x_level popcount
     else:
         raise ValueError(f"unknown EMT mode {cfg.mode!r}")
@@ -246,22 +269,23 @@ def emt_dense(params: dict, x, cfg: EMTConfig, *, tag: str,
         return y, aux
 
     # --- accounting ----------------------------------------------------------
-    w_norm = jax.lax.stop_gradient(
-        jnp.sum(jnp.abs(wq.astype(jnp.float32))) / jnp.maximum(jnp.max(jnp.abs(wq)), 1e-8))
-    rho_sg = jax.lax.stop_gradient(rho)
-    # tile count of this layer on the crossbar fabric (fractional for layers
-    # smaller than one tile — they still only bias a fraction of a macro)
-    n_tiles = (d_in / cfg.crossbar_tile) * max(1.0, d_out / cfg.crossbar_tile)
-    aux["energy_pj"] = (
-        cfg.device.mac_energy(rho_sg, w_norm, x_level, reads_per_cell)
-        + cfg.device.peripheral_energy(n_tokens * n_tiles)
-        # static macro-activation cost: paid once per tile per step window,
-        # NOT per streamed lane — this is what multi-lane verify amortizes.
-        + cfg.device.static_energy(n_tiles))
-    aux["energy_pj"] = jnp.float32(aux["energy_pj"])
-    # Technique B loss term (Eq. 13): alpha * rho * sum|w|, alpha = reads per token
-    # (normalized per-token so lambda has a model-size-independent meaning).
-    aux["reg"] = regularizer.layer_reg_term(wq, rho, alpha=1.0) / d_out
+    with jax.named_scope("emt.energy"):
+        w_norm = jax.lax.stop_gradient(
+            jnp.sum(jnp.abs(wq.astype(jnp.float32))) / jnp.maximum(jnp.max(jnp.abs(wq)), 1e-8))
+        rho_sg = jax.lax.stop_gradient(rho)
+        # tile count of this layer on the crossbar fabric (fractional for layers
+        # smaller than one tile — they still only bias a fraction of a macro)
+        n_tiles = (d_in / cfg.crossbar_tile) * max(1.0, d_out / cfg.crossbar_tile)
+        aux["energy_pj"] = (
+            cfg.device.mac_energy(rho_sg, w_norm, x_level, reads_per_cell)
+            + cfg.device.peripheral_energy(n_tokens * n_tiles)
+            # static macro-activation cost: paid once per tile per step window,
+            # NOT per streamed lane — this is what multi-lane verify amortizes.
+            + cfg.device.static_energy(n_tiles))
+        aux["energy_pj"] = jnp.float32(aux["energy_pj"])
+        # Technique B loss term (Eq. 13): alpha * rho * sum|w|, alpha = reads per token
+        # (normalized per-token so lambda has a model-size-independent meaning).
+        aux["reg"] = regularizer.layer_reg_term(wq, rho, alpha=1.0) / d_out
     aux["reads"] = jnp.float32(n_tokens * d_in)
     aux["cells"] = int(d_in * d_out)
     aux["rho_sum"] = rho_sg

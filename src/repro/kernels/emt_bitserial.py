@@ -80,4 +80,5 @@ def emt_bitserial_pallas(xq, w, rho, *, device: DeviceModel, bits=7, seed=0,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
+        name="emt_bitserial_matmul",
     )(xq, w, sig)

@@ -68,4 +68,5 @@ def emt_matmul_pallas(x, w, rho, *, device: DeviceModel, seed=0, plane=0,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
+        name="emt_matmul",
     )(x, w, sig)

@@ -249,6 +249,8 @@ def _call(q, k_pool, v_pool, table, mask, knew, vnew, wblk, woff, wok, *,
         # operand 8, v_pool 9; outputs 1 and 2 are the pools
         input_output_aliases={8: 1, 9: 2},
         interpret=interpret,
+        # the op's name in HLO and in profiler traces, which readers match
+        name="paged_attention_decode" if has_write else "paged_attention",
     )(table, wblk, woff, wok, q, knew, vnew, mask, k_pool, v_pool)
     return out, k_pool, v_pool
 

@@ -182,4 +182,5 @@ def paged_prefill_pallas(q, k_pool, v_pool, table, qpos, qlast, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, R, hd), jnp.float32),
         interpret=interpret,
+        name="paged_prefill",
     )(table, qlast, q, qpos, k_pool, v_pool)

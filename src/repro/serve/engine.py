@@ -84,6 +84,13 @@ physical RTN picture, matching the pre-continuous-batching engine.  With
 ``fresh_noise=False`` the fluctuation is frozen at the engine seed (static
 programming-noise picture), which makes generation a pure function of the
 request — the property the alone-vs-staggered equivalence tests exercise.
+
+Tracing: every ``step()`` writes host spans (``TraceAnnotation``, recorded
+only while a profiler session is active) on the calling thread —
+``engine.step`` holding ``engine.admit``, ``engine.prepare`` (with
+``engine.tables`` on a paged engine), ``engine.dispatch``, ``engine.wait``,
+``engine.book`` and ``engine.emit`` — on the device trace's clock
+(docs/serving.md, "Tracing").
 """
 from __future__ import annotations
 
@@ -93,6 +100,7 @@ from typing import Callable, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.models import lm
@@ -924,19 +932,21 @@ class ServingEngine:
         prompt (chunked mode), a pure decode step otherwise.  Finally the
         control plane sheds any request that exhausted its energy budget.
         Returns requests finished this step."""
-        finished = self._admit_pending()
-        active = self.scheduler.active_slots()
-        if active:
-            if self.chunked and any(s.prefilling for _, s in active):
-                finished += self._chunk_advance(active)
-            else:
-                finished += self._decode_advance(active)
-        if self.controller is not None:
-            for rid in self.controller.over_budget(self):
-                res = self.cancel(rid, reason="energy_budget")
-                if res is not None:
-                    finished.append(res)
-        return finished
+        with TraceAnnotation("engine.step"):
+            with TraceAnnotation("engine.admit"):
+                finished = self._admit_pending()
+            active = self.scheduler.active_slots()
+            if active:
+                if self.chunked and any(s.prefilling for _, s in active):
+                    finished += self._chunk_advance(active)
+                else:
+                    finished += self._decode_advance(active)
+            if self.controller is not None:
+                for rid in self.controller.over_budget(self):
+                    res = self.cancel(rid, reason="energy_budget")
+                    if res is not None:
+                        finished.append(res)
+            return finished
 
     def _admit_pending(self) -> List[GenResult]:
         """FIFO admission into free slots: stops at the first request the
@@ -966,61 +976,61 @@ class ServingEngine:
         draft-k/verify-one round)."""
         finished = []
         B = self.batch_size
-        tokens = np.zeros(B, np.int32)
-        index = np.zeros(B, np.int32)
-        act = np.zeros(B, bool)
-        seeds = np.zeros(B, np.uint32)
-        spos = np.zeros(B, np.int32)
-        temps = np.zeros(B, np.float32)
-        topk = np.zeros(B, np.int32)
-        topp = np.ones(B, np.float32)
-        enc = np.zeros(B, np.int32)
-        for i, s in active:
-            tokens[i] = s.last_token
-            index[i] = s.pos
-            act[i] = True
-            seeds[i] = np.uint32(s.req.seed)
-            spos[i] = s.sample_pos
-            temps[i] = s.req.temperature
-            topk[i] = s.req.top_k
-            topp[i] = s.req.top_p
-            enc[i] = s.enc_len
+        with TraceAnnotation("engine.prepare"):
+            tokens = np.zeros(B, np.int32)
+            index = np.zeros(B, np.int32)
+            act = np.zeros(B, bool)
+            seeds = np.zeros(B, np.uint32)
+            spos = np.zeros(B, np.int32)
+            temps = np.zeros(B, np.float32)
+            topk = np.zeros(B, np.int32)
+            topp = np.ones(B, np.float32)
+            enc = np.zeros(B, np.int32)
+            for i, s in active:
+                tokens[i] = s.last_token
+                index[i] = s.pos
+                act[i] = True
+                seeds[i] = np.uint32(s.req.seed)
+                spos[i] = s.sample_pos
+                temps[i] = s.req.temperature
+                topk[i] = s.req.top_k
+                topp[i] = s.req.top_p
+                enc[i] = s.enc_len
 
-        self.peak_concurrent = max(self.peak_concurrent, len(active))
-        extra = ()
-        kwargs = {}
-        if self.paged:
-            # append-on-decode: a slot crossing a block boundary draws one of
-            # its reserved blocks before the step writes at pos
-            for i, s in active:
-                if self.scheduler.kv_ensure(i, s.pos):
-                    self._tables_dev = None
-            needs = [1] * self.n_shards
-            for i, s in active:
-                sh = self._shard_of(i)
-                needs[sh] = max(needs[sh], 1 + s.pos)
-            extra, kwargs = self._paged_tables(needs)
-        kwargs["sampled"] = bool((temps > 0).any())
-        step_seed = self.seed + self._steps + 1 if self.fresh_noise else self.seed
-        next_tok, self.cache, eaux = self._decode(
+            self.peak_concurrent = max(self.peak_concurrent, len(active))
+            extra = ()
+            kwargs = {}
+            if self.paged:
+                # append-on-decode: a slot crossing a block boundary draws one of
+                # its reserved blocks before the step writes at pos
+                for i, s in active:
+                    if self.scheduler.kv_ensure(i, s.pos):
+                        self._tables_dev = None
+                needs = [1] * self.n_shards
+                for i, s in active:
+                    sh = self._shard_of(i)
+                    needs[sh] = max(needs[sh], 1 + s.pos)
+                extra, kwargs = self._paged_tables(needs)
+            kwargs["sampled"] = bool((temps > 0).any())
+            step_seed = self.seed + self._steps + 1 if self.fresh_noise else self.seed
+        next_tok, share = self._run_step(active, lambda: self._decode(
             self.params, self.cache, jnp.asarray(tokens), jnp.asarray(index),
             jnp.asarray(act), jnp.uint32(step_seed), jnp.asarray(seeds),
             jnp.asarray(spos), jnp.asarray(temps), jnp.asarray(topk),
-            jnp.asarray(topp), jnp.asarray(enc), *extra, **kwargs)
-        share = self._book_step(eaux, active)
-        next_tok = np.asarray(next_tok)
-        for i, s in active:
-            s.energy_pj += float(share[self._shard_of(i)])
-            s.steps += 1
-            s.pos += 1
-            t = int(next_tok[i])
-            s.last_token = t
-            s.generated.append(t)
-            self._emit(s.rid, t)
-            self._register_decode_blocks(i, s)
-            done = self._maybe_retire(i)
-            if done is not None:
-                finished.append(done)
+            jnp.asarray(topp), jnp.asarray(enc), *extra, **kwargs))
+        with TraceAnnotation("engine.emit"):
+            for i, s in active:
+                s.energy_pj += float(share[self._shard_of(i)])
+                s.steps += 1
+                s.pos += 1
+                t = int(next_tok[i])
+                s.last_token = t
+                s.generated.append(t)
+                self._emit(s.rid, t)
+                self._register_decode_blocks(i, s)
+                done = self._maybe_retire(i)
+                if done is not None:
+                    finished.append(done)
         return finished
 
     def _chunk_advance(self, active) -> List[GenResult]:
@@ -1031,77 +1041,77 @@ class ServingEngine:
         like the pure decode step (every row flows the same C lanes through
         the crossbars); a prefill row's share accrues to its prefill energy."""
         B, C = self.batch_size, self.prefill_chunk
-        tokens = np.zeros((B, C), np.int32)
-        start = np.zeros(B, np.int32)
-        ntok = np.ones(B, np.int32)
-        act = np.zeros(B, bool)
-        seeds = np.zeros(B, np.uint32)
-        spos = np.zeros(B, np.int32)
-        temps = np.zeros(B, np.float32)
-        topk = np.zeros(B, np.int32)
-        topp = np.ones(B, np.float32)
-        for i, s in active:
-            act[i] = True
-            seeds[i] = np.uint32(s.req.seed)
-            spos[i] = s.sample_pos
-            temps[i] = s.req.temperature
-            topk[i] = s.req.top_k
-            topp[i] = s.req.top_p
-            start[i] = s.pos
-            if s.prefilling:
-                take = min(C, len(s.prompt) - s.pos)
-                tokens[i, :take] = s.prompt[s.pos:s.pos + take]
-                ntok[i] = take
-            else:
-                tokens[i, 0] = s.last_token
-        self.peak_concurrent = max(self.peak_concurrent, len(active))
-
-        extra = ()
-        kwargs = {}
-        if self.paged:
+        with TraceAnnotation("engine.prepare"):
+            tokens = np.zeros((B, C), np.int32)
+            start = np.zeros(B, np.int32)
+            ntok = np.ones(B, np.int32)
+            act = np.zeros(B, bool)
+            seeds = np.zeros(B, np.uint32)
+            spos = np.zeros(B, np.int32)
+            temps = np.zeros(B, np.float32)
+            topk = np.zeros(B, np.int32)
+            topp = np.ones(B, np.float32)
             for i, s in active:
-                if not s.prefilling and self.scheduler.kv_ensure(i, s.pos):
-                    self._tables_dev = None
-            needs = [1] * self.n_shards
-            for i, _ in active:
-                sh = self._shard_of(i)
-                needs[sh] = max(needs[sh], int(start[i] + ntok[i]))
-            extra, kwargs = self._paged_tables(needs)
-        kwargs["sampled"] = bool((temps > 0).any())
-        step_seed = self.seed + self._steps + 1 if self.fresh_noise else self.seed
-        next_tok, self.cache, eaux = self._chunk(
+                act[i] = True
+                seeds[i] = np.uint32(s.req.seed)
+                spos[i] = s.sample_pos
+                temps[i] = s.req.temperature
+                topk[i] = s.req.top_k
+                topp[i] = s.req.top_p
+                start[i] = s.pos
+                if s.prefilling:
+                    take = min(C, len(s.prompt) - s.pos)
+                    tokens[i, :take] = s.prompt[s.pos:s.pos + take]
+                    ntok[i] = take
+                else:
+                    tokens[i, 0] = s.last_token
+            self.peak_concurrent = max(self.peak_concurrent, len(active))
+
+            extra = ()
+            kwargs = {}
+            if self.paged:
+                for i, s in active:
+                    if not s.prefilling and self.scheduler.kv_ensure(i, s.pos):
+                        self._tables_dev = None
+                needs = [1] * self.n_shards
+                for i, _ in active:
+                    sh = self._shard_of(i)
+                    needs[sh] = max(needs[sh], int(start[i] + ntok[i]))
+                extra, kwargs = self._paged_tables(needs)
+            kwargs["sampled"] = bool((temps > 0).any())
+            step_seed = self.seed + self._steps + 1 if self.fresh_noise else self.seed
+        next_tok, share = self._run_step(active, lambda: self._chunk(
             self.params, self.cache, jnp.asarray(tokens), jnp.asarray(start),
             jnp.asarray(ntok), jnp.asarray(act), jnp.uint32(step_seed),
             jnp.asarray(seeds), jnp.asarray(spos), jnp.asarray(temps),
-            jnp.asarray(topk), jnp.asarray(topp), *extra, **kwargs)
-        share = self._book_step(eaux, active)
-        next_tok = np.asarray(next_tok)
+            jnp.asarray(topk), jnp.asarray(topp), *extra, **kwargs))
         finished = []
-        for i, s in active:
-            if s.prefilling:
-                s.prefill_energy_pj += float(share[self._shard_of(i)])
-                s.pos += int(ntok[i])
-                self.prefill_tokens_total += int(ntok[i])
-                if self.paged and self.prefix_cache:
-                    # full prompt blocks just written become shareable
-                    self.kv.register_filled(i, s.pos)
-                if not s.prefilling:        # final chunk: first sampled token
+        with TraceAnnotation("engine.emit"):
+            for i, s in active:
+                if s.prefilling:
+                    s.prefill_energy_pj += float(share[self._shard_of(i)])
+                    s.pos += int(ntok[i])
+                    self.prefill_tokens_total += int(ntok[i])
+                    if self.paged and self.prefix_cache:
+                        # full prompt blocks just written become shareable
+                        self.kv.register_filled(i, s.pos)
+                    if not s.prefilling:        # final chunk: first sampled token
+                        t = int(next_tok[i])
+                        s.last_token = t
+                        s.generated.append(t)
+                        self._emit(s.rid, t)
+                else:
+                    s.energy_pj += float(share[self._shard_of(i)])
+                    s.steps += 1
+                    s.pos += 1
                     t = int(next_tok[i])
                     s.last_token = t
                     s.generated.append(t)
                     self._emit(s.rid, t)
-            else:
-                s.energy_pj += float(share[self._shard_of(i)])
-                s.steps += 1
-                s.pos += 1
-                t = int(next_tok[i])
-                s.last_token = t
-                s.generated.append(t)
-                self._emit(s.rid, t)
-                self._register_decode_blocks(i, s)
-            done = self._maybe_retire(i)
-            if done is not None:
-                finished.append(done)
+                    self._register_decode_blocks(i, s)
+                done = self._maybe_retire(i)
+                if done is not None:
+                    finished.append(done)
         return finished
 
     def _paged_tables(self, needs):
@@ -1121,24 +1131,40 @@ class ServingEngine:
 
         Returns (extra_args, kwargs) for the jitted step; shared by the pure
         decode and mixed chunk paths."""
-        self._zero_evicted()
-        buckets = tuple(view_bucket(n, self.block_size, self.max_len)
-                        for n in needs)
-        vlen = max(buckets)
-        key = (vlen, buckets)
-        if self._tables_dev is None or self._tables_dev[0] != key:
-            tg, tl = self.kv.gather_tables()
-            width = -(-vlen // self.block_size)
-            tg = tg[:, :width].copy()
-            if self.n_shards > 1:
-                for sh, b in enumerate(buckets):
-                    w = -(-b // self.block_size)
-                    lo = sh * self.shard_size
-                    tg[lo:lo + self.shard_size, w:] = self.kv.zero_block_g
-            self._tables_dev = (key, jnp.asarray(tg), jnp.asarray(tl))
-        self.view_len = vlen
-        self.shard_view_lens = list(buckets)
-        return self._tables_dev[1:], {"view_len": vlen}
+        with TraceAnnotation("engine.tables"):
+            self._zero_evicted()
+            buckets = tuple(view_bucket(n, self.block_size, self.max_len)
+                            for n in needs)
+            vlen = max(buckets)
+            key = (vlen, buckets)
+            if self._tables_dev is None or self._tables_dev[0] != key:
+                tg, tl = self.kv.gather_tables()
+                width = -(-vlen // self.block_size)
+                tg = tg[:, :width].copy()
+                if self.n_shards > 1:
+                    for sh, b in enumerate(buckets):
+                        w = -(-b // self.block_size)
+                        lo = sh * self.shard_size
+                        tg[lo:lo + self.shard_size, w:] = self.kv.zero_block_g
+                self._tables_dev = (key, jnp.asarray(tg), jnp.asarray(tl))
+            self.view_len = vlen
+            self.shard_view_lens = list(buckets)
+            return self._tables_dev[1:], {"view_len": vlen}
+
+    def _run_step(self, active, call):
+        """Dispatch one jitted step (``call``: the step with its argument
+        uploads), block until the device has run it, and book its aux.
+        Returns the sampled tokens on the host and the per-slot energy shares
+        (:meth:`_book_step`).  The explicit wait gives the host's block on
+        the device a span of its own; the pulls after it find the outputs
+        ready."""
+        with TraceAnnotation("engine.dispatch"):
+            next_tok, self.cache, eaux = call()
+        with TraceAnnotation("engine.wait"):
+            jax.block_until_ready((next_tok, eaux))
+        with TraceAnnotation("engine.book"):
+            share = self._book_step(eaux, active)
+            return np.asarray(next_tok), share
 
     def _book_step(self, eaux, active) -> np.ndarray:
         """Book one jitted step's aux into the engine totals.  Returns the

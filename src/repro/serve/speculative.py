@@ -352,12 +352,12 @@ class SpeculativeEngine(ServingEngine):
                 [int(max(start[i] + ntok[i] for i, _ in active))])
         step_seed = self.seed + self._steps + 1 if self.fresh_noise \
             else self.seed
-        greedy, self.cache, eaux = self._verify(
+        # greedy: (B, C) per-lane target argmax
+        greedy, share = self._run_step(active, lambda: self._verify(
             self.params, self.cache, jnp.asarray(tokens), jnp.asarray(start),
             jnp.asarray(ntok), jnp.asarray(act), jnp.uint32(step_seed),
-            *extra, **kwargs)
-        share = float(self._book_step(eaux, active)[0])
-        greedy = np.asarray(greedy)              # (B, C) per-lane target argmax
+            *extra, **kwargs))
+        share = float(share[0])
 
         # ---- host-side acceptance + commit
         finished = []

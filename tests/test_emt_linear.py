@@ -1,4 +1,6 @@
 """EMT dense layer: modes, accounting, technique-B gradients, energy ordering."""
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -89,3 +91,17 @@ def test_aux_merge():
     a, b = new_aux(), new_aux()
     a["cells"], b["cells"] = 3, 4
     assert add_aux(a, b)["cells"] == 7
+
+
+@pytest.mark.parametrize("mode", ["analog", "bitserial"])
+def test_phase_scopes_in_op_metadata(mode):
+    """Every phase of a call is a named scope under the layer's tag in the
+    ops' HLO op_name metadata, which is what profiler traces group by."""
+    cfg, params = _layer(mode)
+    lowered = jax.jit(lambda p, x: emt_dense(
+        p, x, cfg, tag="layer_000/ffn/wg", seed=3)).lower(params, X)
+    op_names = set(re.findall(r'loc\("([^"]*)"',
+                              lowered.as_text(debug_info=True)))
+    for phase in ("weights", "inputs", "noise", "crossbar", "energy"):
+        assert any(f"layer_000/ffn/wg/emt.{phase}/" in n for n in op_names), \
+            (phase, sorted(op_names))

@@ -12,6 +12,7 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -102,3 +103,53 @@ def test_emt_matmul_compiles(one_chip, kernel):
     rho = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
     op = getattr(ops, kernel)
     _compile(lambda x, w, r: op(x, w, r, device=DEFAULT_DEVICE), x, w, rho)
+
+
+# a one-layer analog deepseek-67b stack at the kernel's head width (128): the
+# serving engine's own jitted paged decode step, lowered with shapes only
+DECODE_SPEC = dict(arch="deepseek-67b", mode="analog", smoke=True,
+                   a_per_row=True, frozen_noise=True, batch_size=8,
+                   max_len=64, paged=True, block_size=BLOCK_SIZE,
+                   prefill_chunk=PREFILL_CHUNK,
+                   model_overrides=dict(num_layers=1, vocab_size=512,
+                                        d_model=256, num_heads=4,
+                                        num_kv_heads=2, head_dim=128,
+                                        d_ff=512))
+
+
+def _lower_decode_step(impl, sharding=None):
+    """Lower the engine's paged decode step (``ServingEngine._decode``) at
+    the engine's own argument shapes, placed by ``sharding`` if given."""
+    import numpy as np
+
+    from repro.serve.spec import ServeSpec
+
+    eng = ServeSpec(**DECODE_SPEC, paged_attn_impl=impl).build_engine()
+    tables, kw = eng._paged_tables([eng.max_len])
+    B = eng.batch_size
+    host = (np.zeros(B, np.int32), np.zeros(B, np.int32),
+            np.ones(B, bool), np.uint32(0), np.zeros(B, np.uint32),
+            np.zeros(B, np.int32), np.zeros(B, np.float32),
+            np.zeros(B, np.int32), np.ones(B, np.float32),
+            np.zeros(B, np.int32))
+    args = (eng.params, eng.cache, *host, *tables)
+    if sharding is not None:
+        args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            np.shape(a), a.dtype, sharding=sharding), args)
+    return eng._decode.lower(*args, sampled=False, **kw)
+
+
+def test_decode_step_module_name():
+    # bench/readers.py finds the decode step's executions by this name
+    text = _lower_decode_step("ref").as_text()
+    assert "module @jit_paged_decode_step" in text
+
+
+def test_decode_step_compiles_with_named_kernel(one_chip):
+    # the decode kernel keeps the op name the roofline reader matches
+    # (paged_attention_decode or paged_attention_decode.<n>), whatever
+    # wraps it
+    hlo = _lower_decode_step("pallas", one_chip).compile().as_text()
+    assert hlo.startswith("HloModule jit_paged_decode_step")
+    assert re.search(r"%paged_attention_decode(\.\d+)? = .*tpu_custom_call",
+                     hlo)
