@@ -22,10 +22,18 @@ projection and phase): ``emt.weights`` (quantize or int8 dequantize),
 ``emt.inputs`` (DAC levels and their read counts), ``emt.noise`` (read
 fluctuation), ``emt.crossbar`` (the matmul) and ``emt.energy`` (the accounting
 from the weights).  Scopes change neither the program's ops nor their fusion.
+
+The weight-only reductions -- the per-column max|w| behind the quantization
+scale, and sum|wq| / max|wq| behind the energy estimate -- read every weight
+in full.  A serving tree carries them precomputed (:func:`with_weight_stats`,
+run once when ``ServingEngine`` is built), so in a served step ``emt.weights``
+and ``emt.energy`` hold only elementwise and scalar work.  A layer dict without
+them (training, or a quantization they were not computed for) reduces in-step.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import zlib
 from typing import Optional
 
@@ -189,6 +197,7 @@ def emt_dense(params: dict, x, cfg: EMTConfig, *, tag: str,
 
 def _emt_dense(params, x, cfg, *, tag, seed, key):
     int8_weights = "w_int8" in params
+    prepared = None if int8_weights else _prepared_stats(params, cfg.quant)
     w = params["w_int8"] if int8_weights else params["w"]
     aux = new_aux()
     d_in, d_out = w.shape
@@ -212,7 +221,8 @@ def _emt_dense(params, x, cfg, *, tag, seed, key):
             # the matmul input on TPU — weight HBM traffic stays int8-sized)
             wq = (w.astype(x.dtype) * params["w_scale"].astype(x.dtype))
         else:
-            wq, _ = quantize_weights(w, cfg.quant)
+            wq, _ = quantize_weights(
+                w, cfg.quant, amax=None if prepared is None else prepared[0])
     # --- activations onto the input lines: quantized DAC levels -------------
     # per_row: each batch row (token) gets its own DAC scale, so quantization
     # never couples co-tenant rows (occupancy-independent serving); per-tensor
@@ -270,8 +280,9 @@ def _emt_dense(params, x, cfg, *, tag, seed, key):
 
     # --- accounting ----------------------------------------------------------
     with jax.named_scope("emt.energy"):
+        norms = _abs_norms(wq) if prepared is None else prepared[1]
         w_norm = jax.lax.stop_gradient(
-            jnp.sum(jnp.abs(wq.astype(jnp.float32))) / jnp.maximum(jnp.max(jnp.abs(wq)), 1e-8))
+            norms["abs_sum"] / jnp.maximum(norms["abs_max"], 1e-8))
         rho_sg = jax.lax.stop_gradient(rho)
         # tile count of this layer on the crossbar fabric (fractional for layers
         # smaller than one tile — they still only bias a fraction of a macro)
@@ -285,7 +296,9 @@ def _emt_dense(params, x, cfg, *, tag, seed, key):
         aux["energy_pj"] = jnp.float32(aux["energy_pj"])
         # Technique B loss term (Eq. 13): alpha * rho * sum|w|, alpha = reads per token
         # (normalized per-token so lambda has a model-size-independent meaning).
-        aux["reg"] = regularizer.layer_reg_term(wq, rho, alpha=1.0) / d_out
+        aux["reg"] = regularizer.layer_reg_term(
+            wq, rho, alpha=1.0,
+            abs_sum=None if prepared is None else norms["abs_sum"]) / d_out
     aux["reads"] = jnp.float32(n_tokens * d_in)
     aux["cells"] = int(d_in * d_out)
     aux["rho_sum"] = rho_sg
@@ -293,3 +306,142 @@ def _emt_dense(params, x, cfg, *, tag, seed, key):
     aux["corners"] = {cfg.corner_label: corner_entry(
         aux["energy_pj"], aux["reads"], aux["cells"])}
     return y, aux
+
+
+# ---------------------------------------------------------------------------
+# weight-only statistics of a serving tree
+# ---------------------------------------------------------------------------
+# Weights do not change while serving, so everything a call derives from the
+# weights alone -- the per-column max|w| behind the quantization scale, and
+# sum|wq| / max|wq| behind the energy estimate and technique B's term -- can
+# be computed once and stored beside the weights under STATS:
+#
+#     {"amax": max|w| per column, (1, d_out),
+#      stats_key(quant): {"abs_sum": sum|wq| (fp32), "abs_max": max|wq|}, ...}
+#
+# A call reads them when its quantization's key is there, and reduces its
+# weight in-step otherwise (training trees never carry them).
+STATS = "emt_stats"
+
+
+def stats_key(quant: QuantConfig) -> str:
+    """Name of the statistics a weight quantization reads: sum|wq| and
+    max|wq| depend on the bit width and the scale's granularity alone."""
+    if not quant.enabled:
+        return "float"
+    return f"w{quant.w_bits}_{'channel' if quant.per_channel else 'tensor'}"
+
+
+def _abs_norms(wq):
+    return {"abs_sum": jnp.sum(jnp.abs(wq.astype(jnp.float32))),
+            "abs_max": jnp.max(jnp.abs(wq))}
+
+
+def _prepared_stats(params, quant: QuantConfig):
+    """(amax, norms) stored for ``quant`` in a layer dict, else None."""
+    stats = params.get(STATS)
+    if stats is None or stats_key(quant) not in stats:
+        return None
+    return stats["amax"], stats[stats_key(quant)]
+
+
+def _weight_stats(w, quants) -> dict:
+    """The statistics of one weight (d_in, d_out) for each QuantConfig of
+    ``quants``.  Axes in front of the matrix stack layers and are kept."""
+    if w.ndim > 2:
+        return jax.vmap(lambda v: _weight_stats(v, quants))(w)
+    amax = jnp.max(jnp.abs(w), axis=0, keepdims=True)
+    out = {"amax": amax}
+    for q in quants:
+        wq, _ = quantize_weights(w, q, amax=amax)
+        out[stats_key(q)] = _abs_norms(wq)
+    return out
+
+
+def _stat_quants(placement) -> tuple:
+    """The distinct weight quantizations (one per :func:`stats_key`) of the
+    active layer configs of a DevicePlacement."""
+    quants = {}
+    for c in [r.emt for r in placement.rules] + [placement.default]:
+        if c.active:
+            quants.setdefault(stats_key(c.quant), c.quant)
+    return tuple(quants.values())
+
+
+def _at(tree, path):
+    for k in path:
+        if not isinstance(tree, dict) or k not in tree:
+            return None
+        tree = tree[k]
+    return tree
+
+
+def _dense_layers(tree, path=()):
+    """(path, dict) of each EMT dense layer: a float weight ``w`` beside its
+    ``rho_raw``, whose axes (if any) stack layers in front of the matrix."""
+    if not isinstance(tree, dict):
+        return
+    if ("w" in tree and "rho_raw" in tree
+            and np.ndim(tree["w"]) == np.ndim(tree["rho_raw"]) + 2):
+        yield path, tree
+        return
+    for k, v in tree.items():
+        yield from _dense_layers(v, path + (k,))
+
+
+@functools.partial(jax.jit, static_argnames=("quants", "tied"))
+def _tree_stats(params, quants, tied):
+    out = {path: _weight_stats(layer["w"], quants)
+           for path, layer in _dense_layers(params)}
+    for path, source in tied:
+        out[path] = _weight_stats(_at(params, source).T, quants)
+    return out
+
+
+def with_weight_stats(params, placement, tied=()):
+    """``params`` with the weight statistics of every EMT dense layer beside
+    its weights, for each quantization the active layers of ``placement``
+    (a DevicePlacement) use.
+
+    ``tied``: (layer path, source path) pairs of layers whose weight is the
+    transpose of another leaf, supplied at call time (a tied LM head).  Only
+    the statistics come out of the device program; the returned tree's new
+    layer dicts point at the caller's weight buffers, so nothing is copied."""
+    quants = _stat_quants(placement)
+    if not quants:
+        return params
+    tied = tuple((path, source) for path, source in tied
+                 if "rho_raw" in (_at(params, path) or {}))
+    stats = _tree_stats(params, quants, tied)
+
+    def attach(node, path):
+        if path in stats:
+            return {**node, STATS: stats[path]}
+        if isinstance(node, dict):
+            return {k: attach(v, path + (k,)) for k, v in node.items()}
+        return node
+    return attach(params, ())
+
+
+def stat_layers(params) -> tuple:
+    """(prepared, inline) counts of a tree's EMT layers -- dicts carrying a
+    ``rho_raw``, a stacked dict counting each layer, an MoE expert stack one
+    -- whose calls read stored weight statistics, and that reduce their
+    weights in-step."""
+    prepared = inline = 0
+    for node in _dicts(params):
+        if "rho_raw" in node:
+            n = int(np.prod(np.shape(node["rho_raw"])))
+            if STATS in node:
+                prepared += n
+            else:
+                inline += n
+    return prepared, inline
+
+
+def _dicts(tree):
+    if isinstance(tree, dict):
+        yield tree
+        for k, v in tree.items():
+            if k != STATS:
+                yield from _dicts(v)

@@ -35,16 +35,25 @@ def _ste(x, q):
     return x + jax.lax.stop_gradient(q - x)
 
 
-def symmetric_scale(x, bits, axis=None, eps=1e-8):
+def scale_from_amax(amax, bits, eps=1e-8):
     qmax = 2.0 ** (bits - 1) - 1.0
-    amax = jnp.max(jnp.abs(x), axis=axis, keepdims=axis is not None)
     return jnp.maximum(amax, eps) / qmax
 
 
-def fake_quant(x, bits, axis=None):
-    """Quantize-dequantize with STE. Returns (x_q_dequant, scale)."""
+def symmetric_scale(x, bits, axis=None, eps=1e-8):
+    amax = jnp.max(jnp.abs(x), axis=axis, keepdims=axis is not None)
+    return scale_from_amax(amax, bits, eps)
+
+
+def fake_quant(x, bits, axis=None, scale=None):
+    """Quantize-dequantize with STE. Returns (x_q_dequant, scale).
+
+    ``scale`` (optional) is a precomputed :func:`symmetric_scale` of ``x``
+    over ``axis``; the elementwise quantize is the same either way."""
     qmax = 2.0 ** (bits - 1) - 1.0
-    scale = jax.lax.stop_gradient(symmetric_scale(x, bits, axis=axis))
+    if scale is None:
+        scale = symmetric_scale(x, bits, axis=axis)
+    scale = jax.lax.stop_gradient(scale)
     q = jnp.clip(jnp.round(x / scale), -qmax, qmax)
     return _ste(x, q * scale), scale
 
@@ -62,11 +71,21 @@ def quant_levels(x, bits, axis=None):
     return q, scale
 
 
-def quantize_weights(w, cfg: QuantConfig):
+def quantize_weights(w, cfg: QuantConfig, amax=None):
+    """Fake-quantize a weight to ``cfg.w_bits`` levels.
+
+    ``amax`` (optional) is the weight's precomputed per-column ``max|w|``
+    (over all axes but the last, kept as size-1 axes): the scale is then
+    derived from it instead of reducing ``w`` again.  A per-tensor scale
+    takes the max of the column maxes, which is the same number."""
     if not cfg.enabled:
         return w, None
     axis = tuple(range(w.ndim - 1)) if cfg.per_channel else None
-    wq, scale = fake_quant(w, cfg.w_bits, axis=axis)
+    scale = None
+    if amax is not None:
+        scale = scale_from_amax(amax if cfg.per_channel else jnp.max(amax),
+                                cfg.w_bits)
+    wq, scale = fake_quant(w, cfg.w_bits, axis=axis, scale=scale)
     return wq, scale
 
 

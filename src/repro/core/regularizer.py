@@ -30,9 +30,14 @@ def rho_from_raw(rho_raw):
     return jax.nn.softplus(rho_raw) + RHO_MIN
 
 
-def layer_reg_term(w, rho, alpha: float):
-    """alpha * rho * sum|w|  — differentiable in both w and rho."""
-    return alpha * rho * jnp.sum(jnp.abs(w.astype(jnp.float32)))
+def layer_reg_term(w, rho, alpha: float, abs_sum=None):
+    """alpha * rho * sum|w|  — differentiable in both w and rho.
+
+    ``abs_sum``: a precomputed sum|w| (a serving tree's weight statistics;
+    no gradient reaches ``w`` through it)."""
+    if abs_sum is None:
+        abs_sum = jnp.sum(jnp.abs(w.astype(jnp.float32)))
+    return alpha * rho * abs_sum
 
 
 def total_energy_loss(reg_terms, lam: float):

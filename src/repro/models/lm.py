@@ -87,6 +87,12 @@ def _encode(params, batch, cfg: ModelConfig, ctx: Ctx):
     return common.rmsnorm(params["enc_norm"], y, cfg.norm_eps), pos, aux
 
 
+def tied_weights(cfg: ModelConfig) -> tuple:
+    """(layer path, source path) of EMT dense layers whose weight is another
+    leaf's transpose: a tied LM head reads the embedding table."""
+    return ((("lm_head",), ("embed", "table")),) if cfg.tie_embeddings else ()
+
+
 def _logits(params, h, cfg: ModelConfig, ctx: Ctx):
     tied = params["embed"]["table"] if cfg.tie_embeddings else None
     p = params.get("lm_head", {})

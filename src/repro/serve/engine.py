@@ -103,6 +103,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from repro.core.emt_linear import stat_layers, with_weight_stats
 from repro.models import lm
 from repro.models.config import ModelConfig
 from repro.models.context import Ctx
@@ -599,6 +600,14 @@ class ServingEngine:
             cfg = cfg.replace(emt=placement)
         self.cfg = cfg
         self.params = params
+        # the tree every step program reads: the caller's weights with each
+        # active EMT dense layer's weight-only statistics computed once here
+        # (core/emt_linear.py, "weight-only statistics"), so no step reduces
+        # a weight that cannot change while serving
+        self.step_params = with_weight_stats(params, cfg.placement,
+                                             lm.tied_weights(cfg))
+        self.emt_prepared_layers, self.emt_inline_layers = stat_layers(
+            self.step_params)
         self.batch_size = batch_size
         self.max_len = max_len
         self.seed = seed
@@ -748,8 +757,8 @@ class ServingEngine:
         if sharded:
             # replicate params across the mesh once (weight noise is seeded,
             # so every shard regenerates identical fluctuations per step)
-            self.params = jax.device_put(
-                self.params, NamedSharding(mesh, PartitionSpec()))
+            self.step_params = jax.device_put(
+                self.step_params, NamedSharding(mesh, PartitionSpec()))
         # refcounted prefix caching: shared prompt-prefix blocks are reused
         # across requests (paged + chunked only; ring/recurrent/enc-dec state
         # cannot be shared across requests, so those stacks are refused)
@@ -1014,7 +1023,7 @@ class ServingEngine:
             kwargs["sampled"] = bool((temps > 0).any())
             step_seed = self.seed + self._steps + 1 if self.fresh_noise else self.seed
         next_tok, share = self._run_step(active, lambda: self._decode(
-            self.params, self.cache, jnp.asarray(tokens), jnp.asarray(index),
+            self.step_params, self.cache, jnp.asarray(tokens), jnp.asarray(index),
             jnp.asarray(act), jnp.uint32(step_seed), jnp.asarray(seeds),
             jnp.asarray(spos), jnp.asarray(temps), jnp.asarray(topk),
             jnp.asarray(topp), jnp.asarray(enc), *extra, **kwargs))
@@ -1081,7 +1090,7 @@ class ServingEngine:
             kwargs["sampled"] = bool((temps > 0).any())
             step_seed = self.seed + self._steps + 1 if self.fresh_noise else self.seed
         next_tok, share = self._run_step(active, lambda: self._chunk(
-            self.params, self.cache, jnp.asarray(tokens), jnp.asarray(start),
+            self.step_params, self.cache, jnp.asarray(tokens), jnp.asarray(start),
             jnp.asarray(ntok), jnp.asarray(act), jnp.uint32(step_seed),
             jnp.asarray(seeds), jnp.asarray(spos), jnp.asarray(temps),
             jnp.asarray(topk), jnp.asarray(topp), *extra, **kwargs))
@@ -1356,6 +1365,8 @@ class ServingEngine:
             "kv_reads_total": float(self.kv_reads_total),
             "prefill_tokens_total": int(self.prefill_tokens_total),
             "cached_prefix_tokens": int(self.cached_prefix_tokens),
+            "emt_prepared_layers": int(self.emt_prepared_layers),
+            "emt_inline_layers": int(self.emt_inline_layers),
         }
 
     def energy_conserved(self, results, rtol: float = 1e-6) -> bool:
@@ -1450,7 +1461,7 @@ class ServingEngine:
         if self.cfg.is_encdec:
             batch["enc_embeds"] = jnp.zeros((1, S, self.cfg.d_model), jnp.float32)
         small = lm.init_cache(self.cfg, 1, self.max_len)
-        small, logits, aux = self._prefill(self.params, batch, small,
+        small, logits, aux = self._prefill(self.step_params, batch, small,
                                            jnp.uint32(self.seed))
         if self.paged:
             ok = self.scheduler.kv_admit(slot_id, S, req.max_new)

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.emt_linear import with_weight_stats
 from repro.core.placement import emt_for_corner
 from repro.models import lm
 from repro.serve.engine import (GenResult, ServingEngine, make_chunk_step,
@@ -112,6 +113,9 @@ class SpeculativeEngine(ServingEngine):
             draft_placement = emt_for_corner(draft_placement)
         self.draft_cfg = self.cfg.replace(emt=draft_placement)
         self.draft_params = params if draft_params is None else draft_params
+        self.draft_step_params = with_weight_stats(
+            self.draft_params, self.draft_cfg.placement,
+            lm.tied_weights(self.draft_cfg))
         # contiguous shadow cache — the draft never pages (its placement is
         # dense digital; the paged machinery belongs to the target)
         self.draft_cache = lm.init_cache(self.draft_cfg, batch_size, max_len)
@@ -281,7 +285,7 @@ class SpeculativeEngine(ServingEngine):
             step_seed = self.seed + self._steps + 1 if self.fresh_noise \
                 else self.seed
             _, self.draft_cache, eaux = self._draft_chunk(
-                self.draft_params, self.draft_cache, jnp.asarray(tokm),
+                self.draft_step_params, self.draft_cache, jnp.asarray(tokm),
                 jnp.asarray(posm), jnp.asarray(ntokm),
                 jnp.asarray(actm), jnp.uint32(step_seed),
                 jnp.asarray(zerosm.astype(np.uint32)), jnp.asarray(zerosm),
@@ -307,7 +311,7 @@ class SpeculativeEngine(ServingEngine):
             step_seed = self.seed + self._steps + 1 if self.fresh_noise \
                 else self.seed
             next_tok, self.draft_cache, eaux = self._draft_decode(
-                self.draft_params, self.draft_cache, jnp.asarray(cur_tok),
+                self.draft_step_params, self.draft_cache, jnp.asarray(cur_tok),
                 jnp.asarray(cur_pos), jnp.asarray(act),
                 jnp.uint32(step_seed), jnp.asarray(zeros_i.astype(np.uint32)),
                 jnp.asarray(zeros_i), jnp.zeros(B, jnp.float32),
@@ -354,7 +358,7 @@ class SpeculativeEngine(ServingEngine):
             else self.seed
         # greedy: (B, C) per-lane target argmax
         greedy, share = self._run_step(active, lambda: self._verify(
-            self.params, self.cache, jnp.asarray(tokens), jnp.asarray(start),
+            self.step_params, self.cache, jnp.asarray(tokens), jnp.asarray(start),
             jnp.asarray(ntok), jnp.asarray(act), jnp.uint32(step_seed),
             *extra, **kwargs))
         share = float(share[0])

@@ -3,10 +3,13 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core import EMTConfig, emt_dense, dense_specs, QuantConfig
-from repro.core.emt_linear import add_aux, new_aux
+from repro.core.emt_linear import (STATS, add_aux, new_aux, stat_layers,
+                                   stats_key, with_weight_stats)
+from repro.core.placement import as_placement
 from repro.core.regularizer import rho_from_raw, rho_init_raw
 from repro.nn.param import init_params
 
@@ -105,3 +108,86 @@ def test_phase_scopes_in_op_metadata(mode):
     for phase in ("weights", "inputs", "noise", "crossbar", "energy"):
         assert any(f"layer_000/ffn/wg/emt.{phase}/" in n for n in op_names), \
             (phase, sorted(op_names))
+
+
+# ---------------------------------------------------------------------------
+# weight-only statistics computed once (emt_linear.with_weight_stats)
+# ---------------------------------------------------------------------------
+def _prepared(layer, cfg):
+    return with_weight_stats({"l": layer}, as_placement(cfg))["l"]
+
+
+def _close(a, b, rtol=1e-6):
+    a, b = float(a), float(b)
+    return abs(a - b) <= rtol * max(abs(a), abs(b))
+
+
+@pytest.mark.parametrize("mode", ["analog", "bitserial"])
+@pytest.mark.parametrize("per_channel", [True, False])
+@pytest.mark.parametrize("w_bits", [8, 4])
+def test_prepared_stats_match_in_step(mode, per_channel, w_bits):
+    cfg, params = _layer(mode, quant=QuantConfig(w_bits=w_bits,
+                                                 per_channel=per_channel))
+    prepared = _prepared(params, cfg)
+    assert prepared["w"] is params["w"]              # weights are not copied
+    assert stats_key(cfg.quant) in prepared[STATS]
+    f = jax.jit(lambda p, x: emt_dense(p, x, cfg, tag="t", seed=3))
+    y0, a0 = f(params, X)
+    y1, a1 = f(prepared, X)
+    np.testing.assert_array_equal(np.asarray(y0), np.asarray(y1))
+    assert _close(a0["energy_pj"], a1["energy_pj"])
+    assert _close(a0["reg"], a1["reg"])
+
+
+def _reductions(params, cfg):
+    fn = jax.jit(lambda p: emt_dense(p, X, cfg, tag="t", seed=3))
+    return fn.lower(params).as_text().count("stablehlo.reduce")
+
+
+def test_prepared_stats_key_mismatch_falls_back_in_step():
+    cfg, params = _layer("analog")
+    # statistics stored for 4-bit weights; the call quantizes to 8 bits
+    other = cfg.replace(quant=QuantConfig(w_bits=4))
+    prepared = _prepared(params, other)
+    assert stats_key(cfg.quant) not in prepared[STATS]
+    f = jax.jit(lambda p, x: emt_dense(p, x, cfg, tag="t", seed=3))
+    y0, a0 = f(params, X)
+    y1, a1 = f(prepared, X)
+    np.testing.assert_array_equal(np.asarray(y0), np.asarray(y1))
+    assert float(a0["energy_pj"]) == float(a1["energy_pj"])
+    assert float(a0["reg"]) == float(a1["reg"])
+    # the fallback reduces the weight in-step, as a matching entry does not
+    matched = _prepared(params, cfg)
+    assert _reductions(prepared, cfg) == _reductions(params, cfg) \
+        > _reductions(matched, cfg)
+
+
+def test_prepared_stats_of_a_stacked_tree_are_per_layer():
+    cfg = EMTConfig(mode="analog")
+    layers = [init_params(dense_specs(64, 32, cfg), jax.random.PRNGKey(i))
+              for i in range(3)]
+    stacked = jax.tree.map(lambda *v: jnp.stack(v), *layers)
+    prepared = with_weight_stats({"blocks": stacked},
+                                 as_placement(cfg))["blocks"]
+    key = stats_key(cfg.quant)
+    assert prepared[STATS]["amax"].shape == (3, 1, 32)
+    assert prepared[STATS][key]["abs_sum"].shape == (3,)
+    assert stat_layers({"blocks": prepared}) == (3, 0)
+    for i, layer in enumerate(layers):
+        one = jax.tree.map(lambda v: v[i], prepared)
+        solo = _prepared(layer, cfg)[STATS]
+        np.testing.assert_array_equal(one[STATS]["amax"], solo["amax"])
+        assert _close(one[STATS][key]["abs_sum"], solo[key]["abs_sum"])
+        assert float(one[STATS][key]["abs_max"]) == \
+            float(solo[key]["abs_max"])
+        y0, a0 = emt_dense(layer, X, cfg, tag="t", seed=3)
+        y1, a1 = emt_dense(one, X, cfg, tag="t", seed=3)
+        np.testing.assert_array_equal(np.asarray(y0), np.asarray(y1))
+        assert _close(a0["energy_pj"], a1["energy_pj"])
+
+
+def test_training_and_ideal_trees_carry_no_stats():
+    cfg, params = _layer("ideal")
+    assert with_weight_stats({"l": params}, as_placement(cfg)) == {"l": params}
+    cfg, params = _layer("analog")
+    assert stat_layers({"l": params}) == (0, 1)

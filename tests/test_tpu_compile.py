@@ -132,7 +132,7 @@ def _lower_decode_step(impl, sharding=None):
             np.zeros(B, np.int32), np.zeros(B, np.float32),
             np.zeros(B, np.int32), np.ones(B, np.float32),
             np.zeros(B, np.int32))
-    args = (eng.params, eng.cache, *host, *tables)
+    args = (eng.step_params, eng.cache, *host, *tables)
     if sharding is not None:
         args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
             np.shape(a), a.dtype, sharding=sharding), args)
